@@ -71,6 +71,8 @@ def main(argv=None) -> None:
                     help="write Chrome/Perfetto trace JSON per serving "
                          "row into DIR")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     wanted = None
     if args.sections:

@@ -62,8 +62,8 @@ def _obs_tokens(rt, rep, max_batch: int) -> str:
             f"bank_busy_pct={int(round(bank))}")
 
 
-def _zipf_pairs(rng: np.ndarray, n_items: int, n_tenants: int,
-                s: float = 1.1) -> List[Tuple[int, int]]:
+def zipf_pairs(rng: np.ndarray, n_items: int, n_tenants: int,
+               s: float = 1.1) -> List[Tuple[int, int]]:
     """Assign each tenant a (distinct) catalog pair, Zipfian over pairs:
     pair rank r gets weight 1/r^s, so a few hot pairs dominate - the
     skew that makes batching windows pack well."""
@@ -93,7 +93,7 @@ def _serve_bitmaps(backend: str, n_tenants: int, n_queries: int,
     # keyed on the expression, so serving stays compile-light)
     expr = Expr.var("x") & Expr.var("y")
     tenants = [f"t{i}" for i in range(n_tenants)]
-    pair_of = dict(zip(tenants, _zipf_pairs(rng, n_items, n_tenants)))
+    pair_of = dict(zip(tenants, zipf_pairs(rng, n_items, n_tenants)))
     expected = {}
 
     def next_query(tenant, k):
@@ -213,6 +213,8 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true",
                     help="small workload (CI trace-determinism job)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.quick:
         rows = [_serve_bitmaps(

@@ -55,7 +55,14 @@ class BitmapIndex:
     def add(self, name: str, members: np.ndarray) -> None:
         bits = np.zeros(self.n_users, bool)
         bits[members] = True
-        bv = BitVector.from_bits(bits)
+        self.add_bitvector(name, BitVector.from_bits(bits))
+
+    def add_bitvector(self, name: str, bv: BitVector) -> None:
+        """Add a bitmap that is already packed (bit u = user u), e.g.
+        words loaded in bulk, without a bool array of n_users."""
+        if bv.n_bits != self.n_users:
+            raise ValueError(f"bitmap {name!r} has {bv.n_bits} bits, the "
+                             f"index {self.n_users} users")
         if self.runtime is not None:
             if name in self.resident:   # drop BEFORE picking a neighbor:
                 self.runtime.free(self.resident.pop(name))

@@ -20,16 +20,22 @@ from ..kernels import ops, ref
 
 @dataclasses.dataclass
 class BitWeavingColumn:
-    planes: jnp.ndarray  # (b, words) uint32, MSB-first
+    planes: np.ndarray  # (b, words) uint32 on the host, MSB-first
     n_rows: int
     bits: int
 
     @staticmethod
     def from_values(values: np.ndarray, bits: int) -> "BitWeavingColumn":
+        """Bit-slice on the host (the numpy twin of ``ref.bitslice``): on
+        the device, slicing a 2^28-row column needs more than a v5e
+        chip's 16 GB of HBM. Runtimes upload the planes at first use."""
         n = len(values)
-        pad = (-n) % 32
-        v = np.pad(values.astype(np.uint32), (0, pad))
-        planes = ref.bitslice(jnp.asarray(v), bits)
+        v = np.pad(values.astype(np.uint32), (0, (-n) % 32))
+        planes = np.empty((bits, v.size // 32), np.uint32)
+        for i in range(bits):
+            bit = (v >> np.uint32(bits - 1 - i)) & np.uint32(1)
+            planes[i] = np.packbits(bit.astype(np.uint8),
+                                    bitorder="little").view("<u4")
         return BitWeavingColumn(planes, n, bits)
 
     def scan_between(self, c1: int, c2: int,

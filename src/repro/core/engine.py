@@ -3,8 +3,9 @@
 Three interchangeable backends compute identical results:
 
   * "jnp"       - jitted jax.numpy over packed uint32 (portable reference).
-  * "pallas"    - fused Pallas TPU kernel per expression (interpret=True on
-                  CPU); the TPU-native realization of AAP-chain fusion.
+  * "pallas"    - fused Pallas TPU kernel per expression (the Pallas
+                  interpreter on the CPU backend only); the TPU-native
+                  realization of AAP-chain fusion.
   * "ambit_sim" - the bit-accurate DRAM device model (core/simulator.py),
                   which also returns the paper's DRAM timing/energy ledger.
 
@@ -127,8 +128,9 @@ def _device_compiled(expression: E.Expr, names: tuple, backend: str,
     shapes specialize inside ``jax.jit`` exactly as ``data_rows`` does in
     the AAP cache. ``donate_idx`` donates that operand's buffer to XLA
     (``out=``-style in-place rebinds: the result reuses the rebound
-    handle's storage instead of allocating). Donation is requested only
-    off-CPU - the CPU runtime cannot honor it and would warn."""
+    handle's storage instead of allocating). Every backend honors the
+    donation, the CPU included, so a read of a donated buffer fails in
+    the CPU tests as it would on the chip."""
     def compute(*arrays):
         env = dict(zip(names, arrays))
         if backend == "pallas":
@@ -139,8 +141,7 @@ def _device_compiled(expression: E.Expr, names: tuple, backend: str,
         from .bitvector import _mask_tail
         return _mask_tail(out, n_bits)
 
-    donate = () if donate_idx is None or jax.default_backend() == "cpu" \
-        else (donate_idx,)
+    donate = () if donate_idx is None else (donate_idx,)
     return jax.jit(compute, donate_argnums=donate)
 
 

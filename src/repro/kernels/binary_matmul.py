@@ -57,10 +57,10 @@ def _bmm_kernel(k_bits: int):
 @functools.partial(jax.jit, static_argnames=("k_bits", "block_m", "block_n",
                                              "block_k_words", "interpret"))
 def binary_matmul(a_packed: jnp.ndarray, b_packed: jnp.ndarray, k_bits: int,
+                  *, interpret: bool,
                   block_m: int = DEFAULT_BLOCK_M,
                   block_n: int = DEFAULT_BLOCK_N,
-                  block_k_words: int = DEFAULT_BLOCK_K_WORDS,
-                  interpret: bool = True) -> jnp.ndarray:
+                  block_k_words: int = DEFAULT_BLOCK_K_WORDS) -> jnp.ndarray:
     """(M, Kw) x (N, Kw) packed uint32 -> (M, N) int32 = K - 2*popcnt(xor).
 
     Padding bits beyond k_bits must be zero in both operands (0 XOR 0

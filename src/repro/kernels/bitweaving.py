@@ -52,9 +52,9 @@ def _bw_kernel(b: int, c1: int, c2: int):
 
 @functools.partial(jax.jit, static_argnames=("c1", "c2", "block_words",
                                              "interpret"))
-def bitweaving_scan(planes: jnp.ndarray, c1: int, c2: int,
-                    block_words: int = DEFAULT_BLOCK_WORDS,
-                    interpret: bool = True) -> jnp.ndarray:
+def bitweaving_scan(planes: jnp.ndarray, c1: int, c2: int, *,
+                    interpret: bool,
+                    block_words: int = DEFAULT_BLOCK_WORDS) -> jnp.ndarray:
     """(b, words) uint32 planes -> (words,) packed predicate bitvector."""
     b, words = planes.shape
     bw = min(block_words, words)

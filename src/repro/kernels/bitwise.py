@@ -44,9 +44,9 @@ def _expr_kernel(expression: E.Expr, names: Tuple[str, ...]):
                                     "block_words", "interpret"))
 def fused_bitwise(expression: E.Expr, names: Tuple[str, ...],
                   *arrays: jnp.ndarray,
+                  interpret: bool,
                   block_rows: int = DEFAULT_BLOCK_ROWS,
-                  block_words: int = DEFAULT_BLOCK_WORDS,
-                  interpret: bool = True) -> jnp.ndarray:
+                  block_words: int = DEFAULT_BLOCK_WORDS) -> jnp.ndarray:
     """Evaluate `expression` over equal-shaped (rows, words) uint32 arrays."""
     rows, words = arrays[0].shape
     br = min(block_rows, rows)
@@ -68,9 +68,10 @@ def fused_bitwise(expression: E.Expr, names: Tuple[str, ...],
                                     "block_words", "interpret"))
 def fused_bitwise_stacked(expression: E.Expr, names: Tuple[str, ...],
                           *arrays: jnp.ndarray,
+                          interpret: bool,
                           block_rows: int = DEFAULT_BLOCK_ROWS,
-                          block_words: int = DEFAULT_BLOCK_WORDS,
-                          interpret: bool = True) -> jnp.ndarray:
+                          block_words: int = DEFAULT_BLOCK_WORDS
+                          ) -> jnp.ndarray:
     """Multi-query fusion: evaluate `expression` over ``(queries, rows,
     words)`` uint32 stacks in ONE kernel launch. The leading grid axis
     walks the query dimension, so an epoch of shape-compatible queries

@@ -1,7 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Handles padding to lane-aligned tile multiples, backend selection
-(interpret=True everywhere except real TPU), and shape normalization.
+(the Pallas interpreter on the CPU backend only; every other backend
+compiles the kernels for its device), and shape normalization.
 These are the entry points the BulkBitwiseEngine's "pallas" backend and
 the model stack use.
 """
@@ -23,7 +24,7 @@ from . import popcount as _pc
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 # -- fused-dispatch probe ------------------------------------------------------

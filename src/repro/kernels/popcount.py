@@ -36,9 +36,9 @@ def _popcount_kernel(x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_words",
                                              "interpret"))
-def popcount_rows(x: jnp.ndarray, block_rows: int = DEFAULT_BLOCK_ROWS,
-                  block_words: int = DEFAULT_BLOCK_WORDS,
-                  interpret: bool = True) -> jnp.ndarray:
+def popcount_rows(x: jnp.ndarray, *, interpret: bool,
+                  block_rows: int = DEFAULT_BLOCK_ROWS,
+                  block_words: int = DEFAULT_BLOCK_WORDS) -> jnp.ndarray:
     """(rows, words) uint32 -> (rows,) int32 popcounts."""
     rows, words = x.shape
     br = min(block_rows, rows)

@@ -240,23 +240,32 @@ class DeviceStore(LruSpillBase):
     def popcount(self, rbv: DeviceBitVector) -> int:
         """Count set bits WITHOUT reading the bitvector back: the
         reduction runs on the accelerator (pallas popcount kernel on the
-        pallas backend, ``lax.population_count`` on jnp) and only the
-        int32 total crosses to the host - 4 ledger bytes instead of the
-        whole array. Device arrays are tail-masked by construction
-        (put data comes from packed BitVectors; planner results are
-        masked in ``_device_compiled``), so the full-array count is
-        exact. Spilled handles count their current host copy for free."""
+        pallas backend, ``lax.population_count`` on jnp) and only one
+        int32 count per row crosses to the host - 4 ledger bytes per row
+        instead of the whole array. Rows sum on the host in int64, and a
+        handle with 2^31 or more bits per row is refused rather than
+        wrapping its int32 count. Device arrays are tail-masked by
+        construction (put data comes from packed BitVectors; planner
+        results are masked in ``_device_compiled``), so the full-array
+        count is exact. Spilled handles count their current host copy
+        for free."""
         self._check_handle(rbv)
+        if rbv.words32 * 32 >= 2 ** 31:
+            raise AmbitError(
+                f"popcount of {rbv!r}: {rbv.words32 * 32} bits per row "
+                "reach 2^31 and would wrap the int32 per-row count")
         if rbv.spilled:
-            return int(np.asarray(rbv._host.popcount()).sum())
+            return int(np.asarray(rbv._host.popcount(), np.int64).sum())
         self._touch(rbv)
         dev = rbv._dev.reshape(-1, rbv.words32)
         if self.backend == "pallas":
             from ..kernels import ops as kops
-            total = int(jnp.sum(kops.popcount(dev)))
+            per_row = kops.popcount(dev)
         else:
-            total = int(jax.lax.population_count(dev).sum())
-        self._charge_io("from_device", "popcount", 4)   # one int32 scalar
+            per_row = jax.lax.population_count(dev).astype(
+                jnp.int32).sum(-1)
+        total = int(np.asarray(per_row, np.int64).sum())
+        self._charge_io("from_device", "popcount", 4 * dev.shape[0])
         return total
 
 

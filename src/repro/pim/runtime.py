@@ -361,9 +361,9 @@ class AmbitRuntime:
         """Count the set bits of a resident bitvector.
 
         On the accelerator backends the reduction runs device-side
-        (pallas popcount kernel / ``lax.population_count``) and only the
-        int32 total crosses the channel - ``bytes_touched`` charges 4
-        bytes, not the whole array. The DRAM model has no reduction op
+        (pallas popcount kernel / ``lax.population_count``) and only one
+        int32 count per row crosses the channel - ``bytes_touched``
+        charges 4 bytes per row, not the whole array. The DRAM model has no reduction op
         (Section 9.1 future-op), so ``ambit_sim`` still reads the result
         back - the one transfer a resident query pays there."""
         if hasattr(self.store, "popcount"):

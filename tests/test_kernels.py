@@ -223,3 +223,12 @@ def test_tmr_ecc_homomorphism_and_scrub():
     assert n_fixed == 8
     assert np.array_equal(np.asarray(codec.decode(clean).bits()),
                           np.asarray(plain.bits()))
+
+
+@pytest.mark.parametrize("backend,interpret",
+                         [("cpu", True), ("tpu", False), ("gpu", False)])
+def test_interpreter_only_on_cpu_backend(monkeypatch, backend, interpret):
+    """A backend other than the CPU compiles the kernels: nothing falls
+    back to the Pallas interpreter because the backend is not a TPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops._interpret() is interpret

@@ -519,3 +519,15 @@ def test_serve_engine_metrics_counters():
     assert m.counter("serve_requests_completed").value(reason="eos") == 1
     assert m.counter("serve_requests_completed").value(
         reason="max_new_tokens") == 1
+
+
+def test_device_popcount_refuses_int32_overflow():
+    """Per-row counts are int32: a handle with 2^31 bits per row raises
+    instead of wrapping (checked before any device work)."""
+    from repro.pim.device_store import DeviceBitVector, DeviceStore
+    store = DeviceStore("jnp")
+    big = DeviceBitVector(store=store, n_bits=2 ** 31, shape=(),
+                          words32=2 ** 26,
+                          _dev=jax.numpy.zeros(1, jax.numpy.uint32))
+    with pytest.raises(AmbitError, match="2\\^31"):
+        store.popcount(big)
