@@ -357,10 +357,9 @@ def pallas_resident_chain(n_ops: int = 6, rows: int = 64,
     allocation churn), and reads back only the final result - measured
     ``bytes_touched`` must drop >= 2x. Then ``n_queries`` same-shape
     queries submit+drain on the pallas backend: the epoch dispatches as
-    ONE stacked fused kernel (call-count probe), bit-identical to serial
-    eval."""
+    ONE stacked fused kernel (the ``fused_dispatches`` counter),
+    bit-identical to serial eval."""
     from repro.core import BitVector, BulkBitwiseEngine, Expr
-    from repro.kernels import ops as kops
     from repro.pim import AmbitRuntime
 
     rng = np.random.default_rng(0)
@@ -401,11 +400,10 @@ def pallas_resident_chain(n_ops: int = 6, rows: int = 64,
     qbits = rng.integers(0, 2, (n_queries, 2, rows, n_bits)).astype(bool)
     envs = [{"x": rt2.put(BitVector.from_bits(qb[0])),
              "y": rt2.put(BitVector.from_bits(qb[1]))} for qb in qbits]
-    kops.fused_dispatch_reset()
     tickets = [rt2.submit(x & y, env) for env in envs]
     rt2.drain()
     epochs = len(rt2.last_drain.epochs)
-    dispatches = kops.fused_dispatch_count()
+    dispatches = int(rt2.metrics.counter("fused_dispatches").total())
     assert epochs == 1 and dispatches == 1, (epochs, dispatches)
     for t, qb in zip(tickets, qbits):
         assert np.array_equal(np.asarray(rt2.get(t.result).bits()),

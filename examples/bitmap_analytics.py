@@ -105,7 +105,7 @@ def main():
           f"{dev_st.bytes_touched} B (uploads once: "
           f"{rt_dev.store.bytes_to_device} B, read-backs: "
           f"{rt_dev.host_reads}, fused launches: "
-          f"{rt_dev.planner.kernel_launches})")
+          f"{int(rt_dev.metrics.counter('fused_dispatches').total())})")
 
     # Observability: the same ledgers as labeled metric series. Bytes
     # are broken down by WHY they crossed the channel (upload vs

@@ -130,8 +130,9 @@ def _device_compiled(expression: E.Expr, names: tuple, backend: str,
     (``out=``-style in-place rebinds: the result reuses the rebound
     handle's storage instead of allocating). Every backend honors the
     donation, the CPU included, so a read of a donated buffer fails in
-    the CPU tests as it would on the chip."""
-    def compute(*arrays):
+    the CPU tests as it would on the chip. The program is named
+    ``ambit_query`` (``jit_ambit_query`` in a profiler trace)."""
+    def ambit_query(*arrays):
         env = dict(zip(names, arrays))
         if backend == "pallas":
             from ..kernels import ops as kops
@@ -142,7 +143,7 @@ def _device_compiled(expression: E.Expr, names: tuple, backend: str,
         return _mask_tail(out, n_bits)
 
     donate = () if donate_idx is None else (donate_idx,)
-    return jax.jit(compute, donate_argnums=donate)
+    return jax.jit(ambit_query, donate_argnums=donate)
 
 
 @functools.lru_cache(maxsize=256)
@@ -150,8 +151,9 @@ def _device_compiled_stacked(expression: E.Expr, names: tuple, backend: str,
                              n_bits: int):
     """Epoch-stacked variant of ``_device_compiled``: operands are
     ``(queries, rows, words)`` stacks and the whole epoch evaluates in
-    ONE dispatch (one stacked-grid pallas_call on the pallas backend)."""
-    def compute(*arrays):
+    ONE dispatch (one stacked-grid pallas_call on the pallas backend),
+    in a program named ``ambit_epoch``."""
+    def ambit_epoch(*arrays):
         env = dict(zip(names, arrays))
         if backend == "pallas":
             from ..kernels import ops as kops
@@ -161,7 +163,7 @@ def _device_compiled_stacked(expression: E.Expr, names: tuple, backend: str,
         from .bitvector import _mask_tail
         return _mask_tail(out, n_bits)
 
-    return jax.jit(compute)
+    return jax.jit(ambit_epoch)
 
 
 def device_compile_cache_info():

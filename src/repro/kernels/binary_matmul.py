@@ -81,4 +81,5 @@ def binary_matmul(a_packed: jnp.ndarray, b_packed: jnp.ndarray, k_bits: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="binary_matmul",
     )(a_packed, b_packed)

@@ -66,5 +66,6 @@ def bitweaving_scan(planes: jnp.ndarray, c1: int, c2: int, *,
         out_specs=pl.BlockSpec((1, bw), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, words), jnp.uint32),
         interpret=interpret,
+        name="bitweaving_scan",
     )(planes)
     return out[0]

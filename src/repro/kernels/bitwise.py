@@ -60,6 +60,7 @@ def fused_bitwise(expression: E.Expr, names: Tuple[str, ...],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, words), jnp.uint32),
         interpret=interpret,
+        name="fused_bitwise",
     )(*arrays)
 
 
@@ -90,4 +91,5 @@ def fused_bitwise_stacked(expression: E.Expr, names: Tuple[str, ...],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((queries, rows, words), jnp.uint32),
         interpret=interpret,
+        name="fused_bitwise_stacked",
     )(*arrays)

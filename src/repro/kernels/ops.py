@@ -27,28 +27,6 @@ def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-# -- fused-dispatch probe ------------------------------------------------------
-# Counts calls to the fused bitwise entry points at the (un-jitted) wrapper
-# layer - one increment per kernel launch issued by Python. Tests and
-# benchmarks assert "one fused dispatch per epoch" against this counter.
-
-_FUSED_DISPATCHES = 0
-
-
-def _count_dispatch() -> None:
-    global _FUSED_DISPATCHES
-    _FUSED_DISPATCHES += 1
-
-
-def fused_dispatch_count() -> int:
-    return _FUSED_DISPATCHES
-
-
-def fused_dispatch_reset() -> None:
-    global _FUSED_DISPATCHES
-    _FUSED_DISPATCHES = 0
-
-
 def _pad_to(x: jnp.ndarray, mults) -> jnp.ndarray:
     pads = []
     for dim, mult in zip(x.shape, mults):
@@ -90,7 +68,6 @@ def bitwise_eval(expression: E.Expr,
                  env: Dict[str, jnp.ndarray]) -> jnp.ndarray:
     """Fused bitwise expression over packed uint32 arrays of equal shape."""
     names = tuple(sorted(env.keys()))
-    _count_dispatch()
     return _eval_padded(expression, names, env)
 
 
@@ -108,7 +85,6 @@ def bitwise_eval_stacked(expression: E.Expr, names,
     stacked = {
         nm: jnp.stack([jnp.asarray(env[nm], jnp.uint32).reshape(rows, words)
                        for env in envs]) for nm in names}
-    _count_dispatch()
     out = _eval_padded_stacked(expression, names, stacked)
     return [out[k].reshape(shape) for k in range(len(envs))]
 

@@ -51,5 +51,6 @@ def popcount_rows(x: jnp.ndarray, *, interpret: bool,
         out_specs=pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.int32),
         interpret=interpret,
+        name="popcount_rows",
     )(x)
     return out[:, 0]

@@ -48,6 +48,8 @@ from ..core.bitvector import BitVector
 from ..core.engine import (OpStats, _device_compiled,
                            _device_compiled_stacked)
 from ..core.simulator import AmbitError
+from ..obs import (PLANNER_LAUNCH, PLANNER_STACK, PLANNER_STACK_BYTES,
+                   STORE_POPCOUNT, STORE_POPCOUNT_WAIT, host_span)
 from .store import LruSpillBase
 
 
@@ -249,24 +251,26 @@ class DeviceStore(LruSpillBase):
         results are masked in ``_device_compiled``), so the full-array
         count is exact. Spilled handles count their current host copy
         for free."""
-        self._check_handle(rbv)
-        if rbv.words32 * 32 >= 2 ** 31:
-            raise AmbitError(
-                f"popcount of {rbv!r}: {rbv.words32 * 32} bits per row "
-                "reach 2^31 and would wrap the int32 per-row count")
-        if rbv.spilled:
-            return int(np.asarray(rbv._host.popcount(), np.int64).sum())
-        self._touch(rbv)
-        dev = rbv._dev.reshape(-1, rbv.words32)
-        if self.backend == "pallas":
-            from ..kernels import ops as kops
-            per_row = kops.popcount(dev)
-        else:
-            per_row = jax.lax.population_count(dev).astype(
-                jnp.int32).sum(-1)
-        total = int(np.asarray(per_row, np.int64).sum())
-        self._charge_io("from_device", "popcount", 4 * dev.shape[0])
-        return total
+        with host_span(STORE_POPCOUNT):
+            self._check_handle(rbv)
+            if rbv.words32 * 32 >= 2 ** 31:
+                raise AmbitError(
+                    f"popcount of {rbv!r}: {rbv.words32 * 32} bits per row "
+                    "reach 2^31 and would wrap the int32 per-row count")
+            if rbv.spilled:
+                return int(np.asarray(rbv._host.popcount(), np.int64).sum())
+            self._touch(rbv)
+            dev = rbv._dev.reshape(-1, rbv.words32)
+            if self.backend == "pallas":
+                from ..kernels import ops as kops
+                per_row = kops.popcount(dev)
+            else:
+                per_row = jax.lax.population_count(dev).astype(
+                    jnp.int32).sum(-1)
+            with host_span(STORE_POPCOUNT_WAIT):
+                per_row = np.asarray(per_row, np.int64)
+            self._charge_io("from_device", "popcount", 4 * dev.shape[0])
+            return int(per_row.sum())
 
 
 @dataclasses.dataclass
@@ -276,7 +280,6 @@ class DeviceReport:
     and exists so the async scheduler's accounting path is uniform."""
 
     queries: int = 0
-    kernel_launches: int = 0
     donated: int = 0                # out= buffers donated to XLA
     per_bank: Dict[Tuple[int, int], OpStats] = dataclasses.field(
         default_factory=dict)
@@ -292,7 +295,6 @@ class DevicePlanner:
     def __init__(self, store: DeviceStore):
         self.store = store
         self.backend = store.backend
-        self.kernel_launches = 0
         self.last_report: Optional[DeviceReport] = None
 
     # -- scheduler hooks ------------------------------------------------------
@@ -364,7 +366,8 @@ class DevicePlanner:
                 donate_idx = matches[0]
         fn = _device_compiled(expression, tuple(names), self.backend,
                               first.n_bits, donate_idx)
-        out_dev = fn(*[env[nm]._dev for nm in names])
+        with host_span(PLANNER_LAUNCH):
+            out_dev = fn(*[env[nm]._dev for nm in names])
         # Budget the result AFTER the dispatch consumed the operand
         # buffers: cold operands are now legal spill victims, so an
         # exact-fit capacity still runs arbitrarily long chains. A
@@ -372,18 +375,14 @@ class DevicePlanner:
         self.store._make_room(
             first.device_bytes,
             protect=() if donate_idx is None else (donate_to,))
-        self.kernel_launches += 1
-        if self.backend == "pallas":
-            from ..kernels import ops as kops
-            kops._count_dispatch()
         res = DeviceBitVector(
             store=self.store, n_bits=first.n_bits, shape=first.shape,
             words32=first.words32, _dev=out_dev, dirty=True, name=out_name,
             _private=True)
         self.store.adopt(res)
         self.last_report = DeviceReport(
-            queries=1, kernel_launches=1,
-            donated=0 if donate_idx is None else 1, stats=OpStats())
+            queries=1, donated=0 if donate_idx is None else 1,
+            stats=OpStats())
         self._record_dispatch(queries=1,
                               donated=0 if donate_idx is None else 1)
         return res
@@ -425,15 +424,15 @@ class DevicePlanner:
         fn = _device_compiled_stacked(expression, tuple(names),
                                       self.backend, first.n_bits)
         n_rows = int(np.prod(first.shape)) if first.shape else 1
-        stacks = [
-            jnp.stack([job[1][nm]._dev.reshape(n_rows, first.words32)
-                       for job in jobs]) for nm in names]
-        out3 = fn(*stacks)              # (queries, rows, words32)
+        with host_span(PLANNER_STACK, operands=len(names)):
+            stacks = [
+                jnp.stack([job[1][nm]._dev.reshape(n_rows, first.words32)
+                           for job in jobs]) for nm in names]
+        self.store.metrics.counter(PLANNER_STACK_BYTES).inc(
+            len(jobs) * len(names) * first.device_bytes)
+        with host_span(PLANNER_LAUNCH):
+            out3 = fn(*stacks)          # (queries, rows, words32)
         self.store._make_room(len(jobs) * first.device_bytes)
-        self.kernel_launches += 1
-        if self.backend == "pallas":
-            from ..kernels import ops as kops
-            kops._count_dispatch()
         results = []
         for k, (_, _, out_name, _) in enumerate(jobs):
             res = DeviceBitVector(
@@ -443,7 +442,6 @@ class DevicePlanner:
                 dirty=True, name=out_name, _private=True)
             self.store.adopt(res)
             results.append(res)
-        self.last_report = DeviceReport(queries=len(jobs),
-                                        kernel_launches=1, stats=OpStats())
+        self.last_report = DeviceReport(queries=len(jobs), stats=OpStats())
         self._record_dispatch(queries=len(jobs))
         return results

@@ -49,6 +49,7 @@ from ..core import expr as E
 from ..core.engine import OpStats
 from ..core.simulator import AmbitError
 from ..core.timing import refresh_schedule
+from ..obs import PLANNER_EPOCH, SCHEDULER_DRAIN, host_span
 from .faults import FaultError
 
 Resource = Tuple[int, int]          # (device index, bank index)
@@ -464,6 +465,13 @@ class AsyncScheduler:
         submitted, self.pending = self.pending, []
         if not submitted:
             return []
+        with host_span(SCHEDULER_DRAIN, tickets=len(submitted)):
+            return self._drain_submitted(submitted, now_ns, epoch_cost,
+                                         refresh, optimize)
+
+    def _drain_submitted(self, submitted: List[Ticket], now_ns: float,
+                         epoch_cost, refresh: bool,
+                         optimize: bool) -> List[Ticket]:
         if optimize:
             tickets = self.optimizer.rewrite(submitted, now_ns=now_ns)
         else:
@@ -742,7 +750,9 @@ class AsyncScheduler:
                 bytes_touched=(store.bytes_to_device - up0)
                 + (store.bytes_from_device - rd0))
             jobs.append((t.expression, env, t.out_name, t.out))
-        results = self.planner.execute_epoch(jobs)
+        with host_span(PLANNER_EPOCH, queries=len(jobs),
+                       first_ticket=group[0].index):
+            results = self.planner.execute_epoch(jobs)
         for t, res in zip(group, results):
             t.result = self.store.rebind(t.out, res) if t.out is not None \
                 else res

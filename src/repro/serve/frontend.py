@@ -40,7 +40,8 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 from ..core import expr as E
 from ..core.engine import OpStats
 from ..core.simulator import AmbitError
-from ..obs import NULL_TRACER, MetricsRegistry
+from ..obs import (FRONTEND_DRAIN, FRONTEND_SUBMIT, NULL_TRACER,
+                   MetricsRegistry, host_span)
 from ..pim.scheduler import DONE, EpochReport, Ticket
 
 
@@ -260,23 +261,24 @@ class QueryFrontend:
         """Enqueue one query for ``tenant``. ``arrival_ns`` places the
         arrival on the simulated clock (defaults to "now"); the clock
         never runs backwards."""
-        if arrival_ns is not None:
-            self.clock_ns = max(self.clock_ns, float(arrival_ns))
-        q = QueryRecord(seq=self._seq, tenant=tenant,
-                        expression=expression, env=env,
-                        arrival_ns=self.clock_ns if arrival_ns is None
-                        else float(arrival_ns))
-        self._seq += 1
-        if self._first_arrival_ns is None:
-            self._first_arrival_ns = q.arrival_ns
-        self.backlog.append(q)
-        self.metrics.counter("serve_submitted").inc(1, tenant=tenant)
-        if self.tracer.enabled:
-            self.tracer.instant(("frontend",), "arrive", "serve",
-                                ts_ns=q.arrival_ns,
-                                args={"tenant": tenant, "seq": q.seq})
-        self._pump()
-        return q
+        with host_span(FRONTEND_SUBMIT, seq=self._seq):
+            if arrival_ns is not None:
+                self.clock_ns = max(self.clock_ns, float(arrival_ns))
+            q = QueryRecord(seq=self._seq, tenant=tenant,
+                            expression=expression, env=env,
+                            arrival_ns=self.clock_ns if arrival_ns is None
+                            else float(arrival_ns))
+            self._seq += 1
+            if self._first_arrival_ns is None:
+                self._first_arrival_ns = q.arrival_ns
+            self.backlog.append(q)
+            self.metrics.counter("serve_submitted").inc(1, tenant=tenant)
+            if self.tracer.enabled:
+                self.tracer.instant(("frontend",), "arrive", "serve",
+                                    ts_ns=q.arrival_ns,
+                                    args={"tenant": tenant, "seq": q.seq})
+            self._pump()
+            return q
 
     def tick(self, now_ns: float) -> None:
         """Advance the simulated clock (e.g. between sparse arrivals) and
@@ -374,63 +376,65 @@ class QueryFrontend:
 
     def _drain(self, reason: str) -> None:
         group, self.window = self.window, []
-        start_ns = self.clock_ns
-        self.runtime.drain(now_ns=self.clock_ns,
-                           epoch_cost=self._epoch_cost,
-                           optimize=self.optimize)
-        rep = self.runtime.last_drain
-        self.clock_ns = rep.end_ns
-        rc = self.report_counters
-        rc.drains += 1
-        rc.epochs += len(rep.epochs)
-        if reason == "fill":
-            rc.fill_drains += 1
-        elif reason == "deadline":
-            rc.deadline_drains += 1
-        else:
-            rc.flush_drains += 1
-        rc.stats += rep.stats
-        lat_hist = self.metrics.histogram("serve_latency_ns")
-        queue_hist = self.metrics.histogram("serve_queue_ns")
-        for q in group:
-            tk = q.ticket
-            q.finished_ns = tk.finished_ns if tk.finished_ns >= 0.0 \
-                else rep.end_ns
-            self._inflight[q.tenant] = max(0, self.inflight(q.tenant) - 1)
-            if tk.state == DONE:
-                q.result = tk.result
-                if tk.cache_hit:
-                    # per-tenant attribution on the shared optimizer
-                    # counter (total() stays the cross-tenant hit count)
-                    self.metrics.counter("opt_cache_hits").inc(
+        with host_span(FRONTEND_DRAIN, reason=reason, queries=len(group)):
+            start_ns = self.clock_ns
+            self.runtime.drain(now_ns=self.clock_ns,
+                               epoch_cost=self._epoch_cost,
+                               optimize=self.optimize)
+            rep = self.runtime.last_drain
+            self.clock_ns = rep.end_ns
+            rc = self.report_counters
+            rc.drains += 1
+            rc.epochs += len(rep.epochs)
+            if reason == "fill":
+                rc.fill_drains += 1
+            elif reason == "deadline":
+                rc.deadline_drains += 1
+            else:
+                rc.flush_drains += 1
+            rc.stats += rep.stats
+            lat_hist = self.metrics.histogram("serve_latency_ns")
+            queue_hist = self.metrics.histogram("serve_queue_ns")
+            for q in group:
+                tk = q.ticket
+                q.finished_ns = tk.finished_ns if tk.finished_ns >= 0.0 \
+                    else rep.end_ns
+                self._inflight[q.tenant] = max(0, self.inflight(q.tenant) - 1)
+                if tk.state == DONE:
+                    q.result = tk.result
+                    if tk.cache_hit:
+                        # per-tenant attribution on the shared optimizer
+                        # counter (total() stays the cross-tenant hit count)
+                        self.metrics.counter("opt_cache_hits").inc(
+                            1, tenant=q.tenant)
+                elif not self._try_host_fallback(q):
+                    # PIM path unrecoverable and the host can't serve it:
+                    # surface the fault as an error result, never a crash.
+                    q.error = tk.error or f"ticket {tk.state}"
+                    rc.errors += 1
+                    self.metrics.counter("serve_errors").inc(
                         1, tenant=q.tenant)
-            elif not self._try_host_fallback(q):
-                # PIM path unrecoverable and the host can't serve it:
-                # surface the fault as an error result, never a crash.
-                q.error = tk.error or f"ticket {tk.state}"
-                rc.errors += 1
-                self.metrics.counter("serve_errors").inc(1, tenant=q.tenant)
-            ddl = self.quota(q.tenant).deadline_ns
-            if ddl is not None and q.error is None \
-                    and q.latency_ns > ddl:
-                q.timed_out = True      # delivered, but past deadline
-                rc.timeouts += 1
-                self.metrics.counter("serve_timeouts").inc(
-                    1, tenant=q.tenant)
-            if q.error is None:
-                lat_hist.observe(q.latency_ns)
-                queue_hist.observe(q.queue_ns)
-                rc.completed += 1
-                self.metrics.counter("serve_completed").inc(
-                    1, tenant=q.tenant)
-            self.completed.append(q)
-        self.metrics.counter("serve_drains").inc(1, reason=reason)
-        self.metrics.counter("serve_batched_queries").inc(len(group))
-        if self.tracer.enabled:
-            self.tracer.span(("frontend",), f"drain:{reason}", "serve",
-                             start_ns, rep.end_ns - start_ns,
-                             args={"queries": len(group),
-                                   "epochs": len(rep.epochs)})
+                ddl = self.quota(q.tenant).deadline_ns
+                if ddl is not None and q.error is None \
+                        and q.latency_ns > ddl:
+                    q.timed_out = True      # delivered, but past deadline
+                    rc.timeouts += 1
+                    self.metrics.counter("serve_timeouts").inc(
+                        1, tenant=q.tenant)
+                if q.error is None:
+                    lat_hist.observe(q.latency_ns)
+                    queue_hist.observe(q.queue_ns)
+                    rc.completed += 1
+                    self.metrics.counter("serve_completed").inc(
+                        1, tenant=q.tenant)
+                self.completed.append(q)
+            self.metrics.counter("serve_drains").inc(1, reason=reason)
+            self.metrics.counter("serve_batched_queries").inc(len(group))
+            if self.tracer.enabled:
+                self.tracer.span(("frontend",), f"drain:{reason}", "serve",
+                                 start_ns, rep.end_ns - start_ns,
+                                 args={"queries": len(group),
+                                       "epochs": len(rep.epochs)})
 
     def _try_host_fallback(self, q: QueryRecord) -> bool:
         """Degraded-mode execution: when the PIM path failed, re-run the

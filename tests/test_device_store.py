@@ -11,8 +11,8 @@ PimStore keeps rows in simulated DRAM. The harness proves three things:
     uploads/read-backs/spills/fault-ins are charged, and a drain's bytes
     accounting is identical to serial eval of the same queries;
   * multi-query drains fuse - an epoch of shape-compatible queries is
-    ONE stacked kernel launch (call-count probe), with results identical
-    to serial evaluation.
+    ONE stacked kernel launch (the ``fused_dispatches`` counter), with
+    results identical to serial evaluation.
 
 Property tests run under hypothesis when installed; without it they fall
 back to deterministic seeded sweeps over the same generators.
@@ -171,15 +171,14 @@ def test_pallas_drain_launches_one_kernel_per_epoch():
         a = rt.put(BitVector.from_bits(bits[q, 0]))
         b = rt.put(BitVector.from_bits(bits[q, 1]))
         envs.append({"x": a, "y": b})
-    kops.fused_dispatch_reset()
-    launches0 = rt.planner.kernel_launches
+    dispatches = rt.metrics.counter("fused_dispatches")
+    launches0 = dispatches.total()
     tickets = [rt.submit(X & Y, env) for env in envs]
     odd = rt.submit(X | Y, envs[0])          # different expr: new epoch
     rt.drain()
     assert len(rt.last_drain.epochs) == 2
     assert [t.epoch for t in tickets] == [0, 0, 0, 0] and odd.epoch == 1
-    assert rt.planner.kernel_launches - launches0 == 2
-    assert kops.fused_dispatch_count() == 2  # one pallas_call per epoch
+    assert dispatches.total() - launches0 == 2  # one pallas_call per epoch
     for t, b in zip(tickets, bits):
         assert np.array_equal(np.asarray(rt.get(t.result).bits()),
                               b[0] & b[1])

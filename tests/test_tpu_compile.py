@@ -11,6 +11,7 @@ in this one file.
 
 import inspect
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,3 +104,54 @@ def test_interpret_has_no_default():
                binary_matmul.binary_matmul):
         param = inspect.signature(fn).parameters["interpret"]
         assert param.default is inspect.Parameter.empty, fn.__name__
+
+
+def _program_names(fn, *args, **static):
+    """The compiled module's name and its Mosaic kernels' names."""
+    text = fn.lower(*args, **static).compile().as_text()
+    module = re.search(r"^HloModule (\S+?),", text, re.M).group(1)
+    kernels = re.findall(r"%(\w+?)(?:\.\d+)? = \S+ custom-call\(.*"
+                         r"custom_call_target=\"tpu_custom_call\"", text)
+    return module, set(kernels)
+
+
+@pytest.mark.parametrize("program,kernel", [
+    ("fused_bitwise", "fused_bitwise"),
+    ("fused_bitwise_stacked", "fused_bitwise_stacked"),
+    ("popcount_rows", "popcount_rows"),
+    ("bitweaving_scan", "bitweaving_scan"),
+    ("binary_matmul", "binary_matmul"),
+    ("ambit_query", "fused_bitwise"),
+    ("ambit_epoch", "fused_bitwise_stacked"),
+])
+def test_trace_names_are_stable(one_chip, monkeypatch, program, kernel):
+    """A profiler trace labels device work ``jit_<program>:<kernel>``:
+    every pallas_call and both of the planner's jitted programs carry a
+    name of their own, so the labels survive a refactor."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    xy = (X & Y, ("x", "y"))
+    calls = {
+        "fused_bitwise": lambda: (bitwise.fused_bitwise, *xy,
+                                  one_chip(8, 2 ** 14), one_chip(8, 2 ** 14)),
+        "fused_bitwise_stacked": lambda: (
+            bitwise.fused_bitwise_stacked, *xy, one_chip(2, 8, 2 ** 12),
+            one_chip(2, 8, 2 ** 12)),
+        "popcount_rows": lambda: (popcount.popcount_rows,
+                                  one_chip(8, 2 ** 14)),
+        "bitweaving_scan": lambda: (bitweaving.bitweaving_scan,
+                                    one_chip(12, 2 ** 14), 100, 3000),
+        "binary_matmul": lambda: (binary_matmul.binary_matmul,
+                                  one_chip(64, 128), one_chip(64, 128),
+                                  4096),
+        "ambit_query": lambda: (engine._device_compiled.__wrapped__(
+            *xy, "pallas", 2 ** 19, None), one_chip(2 ** 14),
+            one_chip(2 ** 14)),
+        "ambit_epoch": lambda: (engine._device_compiled_stacked.__wrapped__(
+            *xy, "pallas", 2 ** 19), one_chip(2, 1, 2 ** 14),
+            one_chip(2, 1, 2 ** 14)),
+    }
+    fn, *args = calls[program]()
+    static = {} if program.startswith("ambit_") else {"interpret": False}
+    module, kernels = _program_names(fn, *args, **static)
+    assert module == f"jit_{program}"
+    assert kernels == {kernel}
