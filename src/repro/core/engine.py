@@ -149,19 +149,35 @@ def _device_compiled(expression: E.Expr, names: tuple, backend: str,
 @functools.lru_cache(maxsize=256)
 def _device_compiled_stacked(expression: E.Expr, names: tuple, backend: str,
                              n_bits: int):
-    """Epoch-stacked variant of ``_device_compiled``: operands are
-    ``(queries, rows, words)`` stacks and the whole epoch evaluates in
-    ONE dispatch (one stacked-grid pallas_call on the pallas backend),
-    in a program named ``ambit_epoch``."""
+    """Epoch-stacked variant of ``_device_compiled``: the whole epoch
+    evaluates in ONE dispatch (one stacked-grid pallas_call on the pallas
+    backend), in a program named ``ambit_epoch``.
+
+    The program takes every job's operands as they are stored: a flat,
+    job-major tuple of ``len(jobs) * len(names)`` arrays, each of shape
+    ``shape + (words,)``; the number of jobs follows from the argument
+    count, and ``jax.jit`` traces once per epoch size. Inside the trace
+    each operand is stacked per name into ``(queries, rows, words)``, so
+    the stack fuses into the kernel's padding instead of costing the
+    host one eager dispatch per operand. Returns one masked result per
+    job, in the operands' stored shape."""
+    n_names = len(names)
+
     def ambit_epoch(*arrays):
-        env = dict(zip(names, arrays))
+        n_jobs = len(arrays) // n_names
+        shape = arrays[0].shape
+        rows = int(np.prod(shape[:-1]))
+        env = {nm: jnp.stack([arrays[j * n_names + i].reshape(rows, -1)
+                              for j in range(n_jobs)])
+               for i, nm in enumerate(names)}
         if backend == "pallas":
             from ..kernels import ops as kops
             out = kops._eval_padded_stacked(expression, names, env)
         else:
             out = E.eval_expr(expression, env)
         from .bitvector import _mask_tail
-        return _mask_tail(out, n_bits)
+        return tuple(_mask_tail(out[j], n_bits).reshape(shape)
+                     for j in range(n_jobs))
 
     return jax.jit(ambit_epoch)
 

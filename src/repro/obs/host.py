@@ -17,13 +17,15 @@ Every name is spelled here once, and starts with ``repro.``:
     epochs' dispatch and the timeline's accounting;
   * ``PLANNER_EPOCH`` - one epoch's dispatch through
     ``DevicePlanner.execute_epoch``, singleton epochs included;
-  * ``PLANNER_STACK`` - the stacking of a multi-query epoch's operands;
+  * ``PLANNER_STACK`` - the host's gathering of a multi-query epoch's
+    operand buffers (the stacking itself runs inside the epoch program);
   * ``PLANNER_LAUNCH`` - the call of a jitted fused program;
   * ``STORE_POPCOUNT`` - ``DeviceStore.popcount``;
   * ``STORE_POPCOUNT_WAIT`` - its blocking read of the per-row counts.
 
 ``PLANNER_STACK_BYTES`` names the ``MetricsRegistry`` counter of bytes
-written into operand stacks (queries x operands x bytes per operand).
+the epoch program writes into operand stacks on the device (queries x
+operands x bytes per operand).
 """
 
 from __future__ import annotations

@@ -404,7 +404,12 @@ class DevicePlanner:
         """Dispatch one scheduler epoch - ``(expression, env, out_name,
         out_handle)`` jobs sharing a stack key - as ONE stacked kernel
         launch. Singleton epochs take the unstacked path so ``out=``
-        chains keep their buffer donation."""
+        chains keep their buffer donation.
+
+        The host only gathers the operands' stored buffers, job-major;
+        the epoch program stacks them, runs the kernel and hands back one
+        result per job in its stored shape, so no eager stack, slice or
+        reshape runs outside the program."""
         if len(jobs) == 1:
             expression, env, out_name, out = jobs[0]
             donate = out if out is not None and \
@@ -423,22 +428,18 @@ class DevicePlanner:
                     "the scheduler's stack key guarantees this")
         fn = _device_compiled_stacked(expression, tuple(names),
                                       self.backend, first.n_bits)
-        n_rows = int(np.prod(first.shape)) if first.shape else 1
         with host_span(PLANNER_STACK, operands=len(names)):
-            stacks = [
-                jnp.stack([job[1][nm]._dev.reshape(n_rows, first.words32)
-                           for job in jobs]) for nm in names]
+            operands = [job[1][nm]._dev for job in jobs for nm in names]
         self.store.metrics.counter(PLANNER_STACK_BYTES).inc(
             len(jobs) * len(names) * first.device_bytes)
         with host_span(PLANNER_LAUNCH):
-            out3 = fn(*stacks)          # (queries, rows, words32)
+            outs = fn(*operands)        # one per job, shape + (words32,)
         self.store._make_room(len(jobs) * first.device_bytes)
         results = []
-        for k, (_, _, out_name, _) in enumerate(jobs):
+        for out_dev, (_, _, out_name, _) in zip(outs, jobs):
             res = DeviceBitVector(
                 store=self.store, n_bits=first.n_bits, shape=first.shape,
-                words32=first.words32,
-                _dev=out3[k].reshape(first.shape + (first.words32,)),
+                words32=first.words32, _dev=out_dev,
                 dirty=True, name=out_name, _private=True)
             self.store.adopt(res)
             results.append(res)

@@ -18,6 +18,7 @@ Property tests run under hypothesis when installed; without it they fall
 back to deterministic seeded sweeps over the same generators.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -196,6 +197,85 @@ def test_stacked_kernel_matches_per_query():
     for g, env in zip(got, envs):
         want = kops.bitwise_eval(expr, env)
         assert np.array_equal(np.asarray(g), np.asarray(want))
+
+
+def _shared_operand_epoch(rt, size, seed):
+    """``size`` jobs of ``(x | ~y) & z`` over (2, 300)-bit operands: each
+    job has its own x and y, and every job reads one shared z (as every
+    week-and-gender query reads the one gender bitmap)."""
+    rng = np.random.default_rng(seed)
+    bits = rng.integers(0, 2, (size, 2, 2, 300)).astype(bool)
+    z = rt.put(BitVector.from_bits(rng.integers(0, 2, (2, 300)) > 0))
+    envs = [{"x": rt.put(BitVector.from_bits(b[0])),
+             "y": rt.put(BitVector.from_bits(b[1])), "z": z} for b in bits]
+    return (X | ~Y) & Z, envs
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("size", (2, 3, 8))
+def test_stacked_epoch_matches_execute_per_job(backend, size):
+    """A stacked epoch whose jobs share an operand handle gives, job by
+    job and in order, what one unstacked ``execute`` per job gives: the
+    same words, shape and flags."""
+    rt = AmbitRuntime(backend=backend)
+    expr, envs = _shared_operand_epoch(rt, size, seed=size)
+    jobs = [(expr, env, f"q{k}", None) for k, env in enumerate(envs)]
+    got = rt.planner.execute_epoch(jobs)
+    assert rt.planner.last_report.queries == size
+    assert len(got) == size
+    for k, (res, env) in enumerate(zip(got, envs)):
+        want = rt.planner.execute(expr, env, out_name=f"q{k}")
+        assert res._dev.shape == want._dev.shape == (2, env["x"].words32)
+        assert (res.n_bits, res.shape, res.words32) == (
+            want.n_bits, want.shape, want.words32)
+        assert (res.dirty, res._private, res.name) == (True, True, f"q{k}")
+        assert res.store is rt.store and not res.freed
+        assert np.array_equal(np.asarray(res._dev), np.asarray(want._dev))
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_stacked_epoch_stacks_and_slices_inside_its_program(
+        backend, monkeypatch):
+    """A stacked epoch calls its one jitted program once, and no
+    ``jnp.stack``, slice or reshape runs on concrete arrays outside that
+    program's trace."""
+    from repro.pim import device_store
+    rt = AmbitRuntime(backend=backend)
+    expr, envs = _shared_operand_epoch(rt, 3, seed=5)
+    jobs = [(expr, env, None, None) for env in envs]
+    want = [rt.planner.execute(expr, env) for env in envs]
+    calls = []
+    real_compiled = device_store._device_compiled_stacked
+
+    def counted(*key):
+        fn = real_compiled(*key)
+
+        def call(*arrays):
+            calls.append(len(arrays))
+            return fn(*arrays)
+        return call
+
+    def refuse_concrete(real):
+        def guarded(*args, **kwargs):
+            leaves = jax.tree_util.tree_leaves((args, kwargs))
+            if not any(isinstance(x, jax.core.Tracer) for x in leaves):
+                raise AssertionError(f"eager {real.__name__} in an epoch")
+            return real(*args, **kwargs)
+        return guarded
+
+    array_type = type(envs[0]["x"]._dev)
+    monkeypatch.setattr(device_store, "_device_compiled_stacked", counted)
+    monkeypatch.setattr(device_store.jnp, "stack",
+                        refuse_concrete(device_store.jnp.stack))
+    for method in ("__getitem__", "reshape"):
+        monkeypatch.setattr(array_type, method,
+                            refuse_concrete(getattr(array_type, method)))
+    got = rt.planner.execute_epoch(jobs)
+    got += rt.planner.execute_epoch(jobs[:2])
+    monkeypatch.undo()
+    assert calls == [3 * 3, 2 * 3]      # one call per epoch, job-major
+    for res, w in zip(got, want + want[:2]):
+        assert np.array_equal(np.asarray(res._dev), np.asarray(w._dev))
 
 
 def test_drain_dependency_and_out_rebind():

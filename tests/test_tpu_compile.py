@@ -82,15 +82,16 @@ def test_bitweaving_scan_compiles(one_chip):
 
 def test_stacked_epoch_fits_at_2_28_bits(one_chip, monkeypatch):
     """One serving epoch of 8 ``x & y`` queries over 2^28-bit bitmaps,
-    as the DevicePlanner dispatches it, stays well inside the chip's
-    16 GB (the 1-D operands are padded to 8 rows, so it needs ~6.75 GiB
-    where the data is 0.75 GiB)."""
+    as the DevicePlanner dispatches it (16 operands in their stored
+    shape, job-major), stays well inside the chip's 16 GB (the 1-D
+    operands are stacked and padded to 8 rows inside the program, so it
+    needs ~6.75 GiB where the data is 0.75 GiB)."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     # a fresh jit, so no trace cached with the interpreter is reused
     fn = engine._device_compiled_stacked.__wrapped__(
         X & Y, ("x", "y"), "pallas", 2 ** 28)
-    a = one_chip(8, 1, 2 ** 23)
-    mem = _compiled_kernel(fn, a, a).memory_analysis()
+    operands = [one_chip(2 ** 23)] * (8 * 2)
+    mem = _compiled_kernel(fn, *operands).memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < 12 * GiB
@@ -147,8 +148,7 @@ def test_trace_names_are_stable(one_chip, monkeypatch, program, kernel):
             *xy, "pallas", 2 ** 19, None), one_chip(2 ** 14),
             one_chip(2 ** 14)),
         "ambit_epoch": lambda: (engine._device_compiled_stacked.__wrapped__(
-            *xy, "pallas", 2 ** 19), one_chip(2, 1, 2 ** 14),
-            one_chip(2, 1, 2 ** 14)),
+            *xy, "pallas", 2 ** 19), *[one_chip(2 ** 14)] * (2 * 2)),
     }
     fn, *args = calls[program]()
     static = {} if program.startswith("ambit_") else {"interpret": False}
