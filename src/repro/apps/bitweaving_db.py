@@ -16,6 +16,7 @@ import numpy as np
 from ..core import BitVector, BulkBitwiseEngine
 from ..core.bitvector import unpack_bits
 from ..kernels import ops, ref
+from ..obs import PLAN_PREDICATE, host_span
 
 
 @dataclasses.dataclass
@@ -238,23 +239,36 @@ def shared_prefix_ranges(bits: int, n: int, rng) -> list:
     return [(lo, hi) for hi in his]
 
 
+def conjunction_plan(planes, specs):
+    """A multi-column conjunction as one submittable ``(expression,
+    env)`` plan over resident plane handles: ``planes[column]`` lists
+    that column's handles, MSB first, and each ``(column, c1, c2)`` term
+    of ``specs`` is the BitWeaving comparator ``scan_expr`` over them,
+    ANDed together. Column names namespace the plane variables
+    (``{column}_b{i}``), so plans over different column sets compose in
+    one drain."""
+    columns = {col for col, _, _ in specs}
+    with host_span(PLAN_PREDICATE, terms=len(specs),
+                   operands=sum(len(planes[col]) for col in columns)):
+        expr, env = None, {}
+        for col, c1, c2 in specs:
+            handles = planes[col]
+            term = scan_expr(len(handles), int(c1), int(c2),
+                             prefix=f"{col}_b")
+            env.update({f"{col}_b{i}": h for i, h in enumerate(handles)})
+            expr = term if expr is None else expr & term
+        return expr, env
+
+
 def predicate_plan(table: TpchTable, specs, runtime,
                    pin_planes: bool = False):
-    """A multi-column conjunction as one submittable
-    ``(expression, env)`` plan: each ``(column, c1, c2)`` term is the
-    BitWeaving comparator over that column's resident planes (uploaded
-    once per runtime, shared by every later plan), ANDed together.
-    Column names namespace the plane variables, so plans over different
-    column sets compose in one drain."""
-    expr, env = None, {}
-    for col, c1, c2 in specs:
-        column = table.columns[col]
-        planes, _ = ensure_resident_planes(column, runtime,
-                                           pin_planes=pin_planes)
-        term = scan_expr(column.bits, int(c1), int(c2), prefix=f"{col}_b")
-        env.update({f"{col}_b{i}": rbv for i, rbv in enumerate(planes)})
-        expr = term if expr is None else expr & term
-    return expr, env
+    """``conjunction_plan`` over ``table``'s columns, whose planes are
+    uploaded to ``runtime`` at first use and shared by every later
+    plan."""
+    planes = {col: ensure_resident_planes(table.columns[col], runtime,
+                                          pin_planes=pin_planes)[0]
+              for col, _, _ in specs}
+    return conjunction_plan(planes, specs)
 
 
 def zipf_tenant_queries(table: TpchTable, n_tenants: int, n_queries: int,

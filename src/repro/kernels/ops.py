@@ -23,6 +23,18 @@ from . import bitwise as _bitwise
 from . import popcount as _pc
 
 
+# The fused kernels take each operand as (rows, words), padded to whole
+# (sublane, lane) tiles.
+FUSED_TILE = (8, 128)
+
+
+def fused_operand_bytes(rows: int, words: int) -> int:
+    """Bytes of one (rows, words) uint32 operand as the fused kernel
+    receives it, after its padding to whole ``FUSED_TILE`` tiles."""
+    tr, tw = FUSED_TILE
+    return 4 * -(-rows // tr) * tr * -(-words // tw) * tw
+
+
 def _interpret() -> bool:
     return jax.default_backend() == "cpu"
 
@@ -47,7 +59,7 @@ def _eval_padded(expression: E.Expr, names,
     words = shape[-1]
     rows = int(np.prod(lead)) if lead else 1
     arrays = [a.reshape(rows, words) for a in arrays]
-    padded = [_pad_to(a, (8, 128)) for a in arrays]
+    padded = [_pad_to(a, FUSED_TILE) for a in arrays]
     out = _bitwise.fused_bitwise(expression, tuple(names), *padded,
                                  interpret=_interpret())
     return out[:rows, :words].reshape(shape)
@@ -58,7 +70,7 @@ def _eval_padded_stacked(expression: E.Expr, names,
     """(queries, rows, words) stacks -> one stacked-grid kernel launch."""
     arrays = [jnp.asarray(env[n], jnp.uint32) for n in names]
     q, rows, words = arrays[0].shape
-    padded = [_pad_to(a, (1, 8, 128)) for a in arrays]
+    padded = [_pad_to(a, (1, *FUSED_TILE)) for a in arrays]
     out = _bitwise.fused_bitwise_stacked(expression, tuple(names), *padded,
                                          interpret=_interpret())
     return out[:, :rows, :words]

@@ -15,10 +15,10 @@ while a profiler trace is running.
 from .tracer import NULL_TRACER, TraceEvent, Tracer
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .export import chrome_trace, utilization_report, write_chrome_trace
-from .host import (FRONTEND_DRAIN, FRONTEND_SUBMIT, PLANNER_EPOCH,
-                   PLANNER_LAUNCH, PLANNER_STACK, PLANNER_STACK_BYTES,
-                   SCHEDULER_DRAIN, STORE_POPCOUNT, STORE_POPCOUNT_WAIT,
-                   host_span)
+from .host import (FRONTEND_DRAIN, FRONTEND_SUBMIT, PLAN_PREDICATE,
+                   PLANNER_EPOCH, PLANNER_LAUNCH, PLANNER_OPERAND_BYTES,
+                   PLANNER_STACK, PLANNER_STACK_BYTES, SCHEDULER_DRAIN,
+                   STORE_POPCOUNT, STORE_POPCOUNT_WAIT, host_span)
 
 __all__ = [
     "NULL_TRACER",
@@ -40,5 +40,7 @@ __all__ = [
     "PLANNER_LAUNCH",
     "STORE_POPCOUNT",
     "STORE_POPCOUNT_WAIT",
+    "PLAN_PREDICATE",
     "PLANNER_STACK_BYTES",
+    "PLANNER_OPERAND_BYTES",
 ]

@@ -21,11 +21,17 @@ Every name is spelled here once, and starts with ``repro.``:
     operand buffers (the stacking itself runs inside the epoch program);
   * ``PLANNER_LAUNCH`` - the call of a jitted fused program;
   * ``STORE_POPCOUNT`` - ``DeviceStore.popcount``;
-  * ``STORE_POPCOUNT_WAIT`` - its blocking read of the per-row counts.
+  * ``STORE_POPCOUNT_WAIT`` - its blocking read of the per-row counts;
+  * ``PLAN_PREDICATE`` - ``apps.bitweaving_db.conjunction_plan``: the
+    host's build of a BitWeaving conjunction's expression and env, once
+    per query.
 
 ``PLANNER_STACK_BYTES`` names the ``MetricsRegistry`` counter of bytes
 the epoch program writes into operand stacks on the device (queries x
-operands x bytes per operand).
+operands x bytes per operand). ``PLANNER_OPERAND_BYTES`` counts the
+bytes of operands as the fused program receives them, after its row and
+lane padding (queries x operands x padded bytes per operand), at every
+launch, singleton or stacked.
 """
 
 from __future__ import annotations
@@ -40,8 +46,10 @@ PLANNER_STACK = "repro.planner.stack"
 PLANNER_LAUNCH = "repro.planner.launch"
 STORE_POPCOUNT = "repro.store.popcount"
 STORE_POPCOUNT_WAIT = "repro.store.popcount_wait"
+PLAN_PREDICATE = "repro.plan.predicate"
 
 PLANNER_STACK_BYTES = "planner_stack_bytes"
+PLANNER_OPERAND_BYTES = "planner_operand_bytes"
 
 
 def host_span(name: str, **stats) -> TraceAnnotation:

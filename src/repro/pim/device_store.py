@@ -48,8 +48,9 @@ from ..core.bitvector import BitVector
 from ..core.engine import (OpStats, _device_compiled,
                            _device_compiled_stacked)
 from ..core.simulator import AmbitError
-from ..obs import (PLANNER_LAUNCH, PLANNER_STACK, PLANNER_STACK_BYTES,
-                   STORE_POPCOUNT, STORE_POPCOUNT_WAIT, host_span)
+from ..obs import (PLANNER_LAUNCH, PLANNER_OPERAND_BYTES, PLANNER_STACK,
+                   PLANNER_STACK_BYTES, STORE_POPCOUNT, STORE_POPCOUNT_WAIT,
+                   host_span)
 from .store import LruSpillBase
 
 
@@ -366,6 +367,7 @@ class DevicePlanner:
                 donate_idx = matches[0]
         fn = _device_compiled(expression, tuple(names), self.backend,
                               first.n_bits, donate_idx)
+        self._count_operand_bytes(1, len(names), first)
         with host_span(PLANNER_LAUNCH):
             out_dev = fn(*[env[nm]._dev for nm in names])
         # Budget the result AFTER the dispatch consumed the operand
@@ -386,6 +388,19 @@ class DevicePlanner:
         self._record_dispatch(queries=1,
                               donated=0 if donate_idx is None else 1)
         return res
+
+    def _count_operand_bytes(self, queries: int, operands: int,
+                             first: DeviceBitVector) -> None:
+        """Add a launch's operand bytes as its fused program receives
+        them: padded to whole kernel tiles on the pallas backend, as
+        stored on jnp."""
+        per = first.device_bytes
+        if self.backend == "pallas":
+            from ..kernels import ops as kops
+            rows = int(np.prod(first.shape)) if first.shape else 1
+            per = kops.fused_operand_bytes(rows, first.words32)
+        self.store.metrics.counter(PLANNER_OPERAND_BYTES).inc(
+            queries * operands * per)
 
     def _record_dispatch(self, queries: int, donated: int = 0) -> None:
         m = self.store.metrics
@@ -432,6 +447,7 @@ class DevicePlanner:
             operands = [job[1][nm]._dev for job in jobs for nm in names]
         self.store.metrics.counter(PLANNER_STACK_BYTES).inc(
             len(jobs) * len(names) * first.device_bytes)
+        self._count_operand_bytes(len(jobs), len(names), first)
         with host_span(PLANNER_LAUNCH):
             outs = fn(*operands)        # one per job, shape + (words32,)
         self.store._make_room(len(jobs) * first.device_bytes)

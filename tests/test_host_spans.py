@@ -132,3 +132,55 @@ def test_stack_counter_counts_stacked_epochs_only(run):
     per_operand = run["envs"][0]["x"].device_bytes
     assert run["after_stacked"] == STACKED * 2 * per_operand
     assert run["after_singleton"] == run["after_stacked"]
+
+
+Q6_COLUMNS = (("l_shipdate", 12), ("l_discount", 4), ("l_quantity", 6))
+Q6_ROWS = 300
+Q6_SPECS = [(("l_shipdate", 366, 730), ("l_discount", 5, 7),
+             ("l_quantity", 0, 23))] * 2 + \
+    [(("l_shipdate", 1461, 1826), ("l_discount", 1, 3),
+      ("l_quantity", 0, 24))]
+
+
+@pytest.fixture(scope="module")
+def q6_run(tmp_path_factory):
+    """A tiny TPC-H Q6 through the frontend under the profiler: two equal
+    predicates fill a stacked epoch, a third is flushed alone; every plan
+    comes from ``predicate_plan``."""
+    from repro.apps.bitweaving_db import TpchTable, predicate_plan
+    table = TpchTable.synthesize(Q6_ROWS, seed=5, columns=Q6_COLUMNS)
+    rt = AmbitRuntime(backend="pallas")
+    fe = QueryFrontend(rt, max_batch=2)
+    operand_bytes = rt.metrics.counter(obs.PLANNER_OPERAND_BYTES)
+    log_dir = str(tmp_path_factory.mktemp("q6_trace"))
+    jax.profiler.start_trace(log_dir)
+    try:
+        for specs in Q6_SPECS:
+            fe.submit("t", *predicate_plan(table, specs, rt))
+        fe.flush()
+        counts = [rt.popcount(q.result) for q in fe.take_completed()]
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    plans = [{k: v for k, v in ev.stats}
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for ln in plane.lines for ev in ln.events
+             if ev.name == obs.PLAN_PREDICATE]
+    return dict(table=table, counts=counts, plans=plans,
+                operand_bytes=operand_bytes.total())
+
+
+def test_q6_answers_are_right(q6_run):
+    table = q6_run["table"]
+    assert q6_run["counts"] == [int(table.oracle(s).sum()) for s in Q6_SPECS]
+
+
+def test_plan_span_per_query(q6_run):
+    assert q6_run["plans"] == [{"terms": 3, "operands": 22}] * len(Q6_SPECS)
+
+
+def test_operand_bytes_count_the_padded_operands(q6_run):
+    """Each launch adds its operands as the fused program receives them:
+    one row padded to 8, 10 words padded to 128 lanes, 4 bytes a word."""
+    assert q6_run["operand_bytes"] == len(Q6_SPECS) * 22 * 8 * 128 * 4

@@ -51,6 +51,23 @@ def test_popcount_kernel(shape):
     assert np.array_equal(np.asarray(got), np.asarray(ref.popcount(a)))
 
 
+@pytest.mark.parametrize("words", [640, 1000, 5 * 512 + 256, 1024, 2048])
+@pytest.mark.parametrize("fill", ["ones", "random"])
+def test_popcount_rows_counts_no_word_past_the_end(words, fill):
+    """In TPU interpret mode a block that reaches past the array reads
+    uninitialised words (all ones), as the chip reads whatever lies past
+    the buffer: only words within the array may count, also where the
+    word count is not a multiple of the 512-word tile."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import popcount
+    x = jnp.full((8, words), 0xFFFFFFFF, jnp.uint32) if fill == "ones" \
+        else rand_u32((8, words))
+    got = popcount.popcount_rows(x, interpret=pltpu.InterpretParams())
+    want = jax.lax.population_count(x).astype(jnp.int32).sum(1)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("b,n", [(1, 32), (4, 64), (8, 320), (12, 1024),
                                  (16, 4096), (32, 96)])
 def test_bitweaving_kernel(b, n):

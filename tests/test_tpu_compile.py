@@ -75,6 +75,14 @@ def test_popcount_rows_compiles(one_chip):
                      interpret=False)
 
 
+def test_popcount_compiles_past_whole_blocks(one_chip, monkeypatch):
+    """The runtime popcount of one TPC-H Q6 selection at SF30: 5,625,000
+    words, which the wrapper pads to 5,625,088, half a 512-word block
+    past the last whole one, so the kernel masks its last block."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    _compiled_kernel(jax.jit(ops.popcount), one_chip(5_625_000))
+
+
 def test_bitweaving_scan_compiles(one_chip):
     _compiled_kernel(bitweaving.bitweaving_scan, one_chip(12, 2 ** 20),
                      100, 3000, interpret=False)
