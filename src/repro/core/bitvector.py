@@ -112,6 +112,8 @@ class BitVector:
 def _mask_tail(data: jnp.ndarray, n_bits: int) -> jnp.ndarray:
     """Zero the padding bits beyond n_bits (keeps popcounts exact)."""
     words = data.shape[-1]
+    if n_bits == words * WORD:
+        return data
     full_words = n_bits // WORD
     rem = n_bits % WORD
     idx = jnp.arange(words, dtype=jnp.uint32)

@@ -157,27 +157,24 @@ def _device_compiled_stacked(expression: E.Expr, names: tuple, backend: str,
     job-major tuple of ``len(jobs) * len(names)`` arrays, each of shape
     ``shape + (words,)``; the number of jobs follows from the argument
     count, and ``jax.jit`` traces once per epoch size. Inside the trace
-    each operand is stacked per name into ``(queries, rows, words)``, so
-    the stack fuses into the kernel's padding instead of costing the
-    host one eager dispatch per operand. Returns one masked result per
-    job, in the operands' stored shape."""
+    each name's operands are stacked in the kernel's layout, so the
+    stack fuses with the kernel's padding instead of costing the host one
+    eager dispatch per operand. Returns one masked result per job, in the
+    operands' stored shape."""
     n_names = len(names)
 
     def ambit_epoch(*arrays):
-        n_jobs = len(arrays) // n_names
-        shape = arrays[0].shape
-        rows = int(np.prod(shape[:-1]))
-        env = {nm: jnp.stack([arrays[j * n_names + i].reshape(rows, -1)
-                              for j in range(n_jobs)])
-               for i, nm in enumerate(names)}
         if backend == "pallas":
             from ..kernels import ops as kops
-            out = kops._eval_padded_stacked(expression, names, env)
+            outs = kops._eval_padded_stacked(
+                expression, names,
+                {nm: arrays[i::n_names] for i, nm in enumerate(names)})
         else:
-            out = E.eval_expr(expression, env)
+            outs = [E.eval_expr(expression,
+                                dict(zip(names, arrays[j:j + n_names])))
+                    for j in range(0, len(arrays), n_names)]
         from .bitvector import _mask_tail
-        return tuple(_mask_tail(out[j], n_bits).reshape(shape)
-                     for j in range(n_jobs))
+        return tuple(_mask_tail(out, n_bits) for out in outs)
 
     return jax.jit(ambit_epoch)
 

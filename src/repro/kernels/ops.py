@@ -10,7 +10,7 @@ the model stack use.
 from __future__ import annotations
 
 import functools
-from typing import Dict
+from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -23,14 +23,23 @@ from . import bitwise as _bitwise
 from . import popcount as _pc
 
 
-# The fused kernels take each operand as (rows, words), padded to whole
-# (sublane, lane) tiles.
-FUSED_TILE = (8, 128)
+# The fused kernels take a multi-row operand as (rows, words), padded to
+# whole (sublane, lane) tiles. A one-row operand of more than half a
+# LANE_TILE goes to the kernel as its flat (words,) array, read in place:
+# HBM stores such a 1-D uint32 array in tiles of LANE_TILE words, which
+# hold the bytes of one (8, 128) tile. A shorter one (XLA tiles it in 128
+# to 512 words), and any one-row operand in a stack of queries, is padded
+# to whole LANE_TILEs and viewed lane-dense, as (words / 128, 128).
+FUSED_TILE = (_bitwise.SUBLANES, _bitwise.LANES)
+LANE_TILE = _bitwise.SUBLANES * _bitwise.LANES
 
 
 def fused_operand_bytes(rows: int, words: int) -> int:
     """Bytes of one (rows, words) uint32 operand as the fused kernel
-    receives it, after its padding to whole ``FUSED_TILE`` tiles."""
+    receives it: whole ``LANE_TILE``s for one row, else whole
+    ``FUSED_TILE`` tiles."""
+    if rows == 1:
+        return 4 * -(-words // LANE_TILE) * LANE_TILE
     tr, tw = FUSED_TILE
     return 4 * -(-rows // tr) * tr * -(-words // tw) * tw
 
@@ -49,31 +58,55 @@ def _pad_to(x: jnp.ndarray, mults) -> jnp.ndarray:
     return x
 
 
+def _rows_words(shape) -> tuple:
+    return (int(np.prod(shape[:-1])) if shape[:-1] else 1), shape[-1]
+
+
+def _to_kernel(a: jnp.ndarray, stacked: bool = False) -> jnp.ndarray:
+    """A (..., words) operand in the fused kernel's layout (see
+    ``FUSED_TILE``)."""
+    rows, words = _rows_words(a.shape)
+    if rows > 1:
+        return _pad_to(a.reshape(rows, words), FUSED_TILE)
+    if not stacked and words > LANE_TILE // 2:
+        return a.reshape(words)
+    return _pad_to(a.reshape(words), (LANE_TILE,)).reshape(
+        -1, _bitwise.LANES)
+
+
+def _from_kernel(out: jnp.ndarray, shape) -> jnp.ndarray:
+    """The kernel's result for operands of ``shape``, back in that
+    shape: the pad words sliced off."""
+    rows, words = _rows_words(shape)
+    if rows == 1:
+        return out.reshape(-1)[:words].reshape(shape)
+    return out[:rows, :words].reshape(shape)
+
+
 def _eval_padded(expression: E.Expr, names,
                  env: Dict[str, jnp.ndarray]) -> jnp.ndarray:
     """Shape-normalized fused evaluation (shared by the public wrapper and
     the accelerator-resident compiled callables; jit-safe, no counters)."""
     arrays = [jnp.asarray(env[n], jnp.uint32) for n in names]
-    shape = arrays[0].shape
-    lead = shape[:-1]
-    words = shape[-1]
-    rows = int(np.prod(lead)) if lead else 1
-    arrays = [a.reshape(rows, words) for a in arrays]
-    padded = [_pad_to(a, FUSED_TILE) for a in arrays]
-    out = _bitwise.fused_bitwise(expression, tuple(names), *padded,
+    out = _bitwise.fused_bitwise(expression, tuple(names),
+                                 *map(_to_kernel, arrays),
                                  interpret=_interpret())
-    return out[:rows, :words].reshape(shape)
+    return _from_kernel(out, arrays[0].shape)
 
 
 def _eval_padded_stacked(expression: E.Expr, names,
-                         env: Dict[str, jnp.ndarray]) -> jnp.ndarray:
-    """(queries, rows, words) stacks -> one stacked-grid kernel launch."""
-    arrays = [jnp.asarray(env[n], jnp.uint32) for n in names]
-    q, rows, words = arrays[0].shape
-    padded = [_pad_to(a, (1, *FUSED_TILE)) for a in arrays]
-    out = _bitwise.fused_bitwise_stacked(expression, tuple(names), *padded,
+                         jobs: Dict[str, Sequence[jnp.ndarray]]) -> list:
+    """One stacked-grid kernel launch over several queries: ``jobs`` maps
+    each name to its per-query operands, all of one shape (..., words).
+    Each operand takes the kernel's layout before the stack, so one-row
+    operands stack lane-dense. Returns one result per query, in the
+    operands' shape."""
+    stacks = [jnp.stack([_to_kernel(jnp.asarray(a, jnp.uint32), stacked=True)
+                         for a in jobs[n]]) for n in names]
+    out = _bitwise.fused_bitwise_stacked(expression, tuple(names), *stacks,
                                          interpret=_interpret())
-    return out[:, :rows, :words]
+    shape = jnp.shape(jobs[names[0]][0])
+    return [_from_kernel(o, shape) for o in out]
 
 
 def bitwise_eval(expression: E.Expr,
@@ -90,15 +123,8 @@ def bitwise_eval_stacked(expression: E.Expr, names,
     name->(..., words) arrays, all equal-shaped; returns one result array
     per environment."""
     names = tuple(names)
-    first = jnp.asarray(envs[0][names[0]], jnp.uint32)
-    shape = first.shape
-    lead, words = shape[:-1], shape[-1]
-    rows = int(np.prod(lead)) if lead else 1
-    stacked = {
-        nm: jnp.stack([jnp.asarray(env[nm], jnp.uint32).reshape(rows, words)
-                       for env in envs]) for nm in names}
-    out = _eval_padded_stacked(expression, names, stacked)
-    return [out[k].reshape(shape) for k in range(len(envs))]
+    return _eval_padded_stacked(
+        expression, names, {nm: [env[nm] for env in envs] for nm in names})
 
 
 def popcount(x: jnp.ndarray) -> jnp.ndarray:

@@ -143,32 +143,46 @@ Q6_SPECS = [(("l_shipdate", 366, 730), ("l_discount", 5, 7),
 
 
 @pytest.fixture(scope="module")
-def q6_run(tmp_path_factory):
-    """A tiny TPC-H Q6 through the frontend under the profiler: two equal
+def q6_runs(tmp_path_factory):
+    """``q6_runs(rows)``: a tiny TPC-H Q6 over ``rows`` rows through the
+    frontend under the profiler, run once per row count: two equal
     predicates fill a stacked epoch, a third is flushed alone; every plan
     comes from ``predicate_plan``."""
     from repro.apps.bitweaving_db import TpchTable, predicate_plan
-    table = TpchTable.synthesize(Q6_ROWS, seed=5, columns=Q6_COLUMNS)
-    rt = AmbitRuntime(backend="pallas")
-    fe = QueryFrontend(rt, max_batch=2)
-    operand_bytes = rt.metrics.counter(obs.PLANNER_OPERAND_BYTES)
-    log_dir = str(tmp_path_factory.mktemp("q6_trace"))
-    jax.profiler.start_trace(log_dir)
-    try:
-        for specs in Q6_SPECS:
-            fe.submit("t", *predicate_plan(table, specs, rt))
-        fe.flush()
-        counts = [rt.popcount(q.result) for q in fe.take_completed()]
-    finally:
-        jax.profiler.stop_trace()
-    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
-    plans = [{k: v for k, v in ev.stats}
-             for plane in ProfileData.from_file(path).planes
-             if plane.name.startswith("/host:")
-             for ln in plane.lines for ev in ln.events
-             if ev.name == obs.PLAN_PREDICATE]
-    return dict(table=table, counts=counts, plans=plans,
-                operand_bytes=operand_bytes.total())
+    runs = {}
+
+    def run(rows):
+        if rows in runs:
+            return runs[rows]
+        table = TpchTable.synthesize(rows, seed=5, columns=Q6_COLUMNS)
+        rt = AmbitRuntime(backend="pallas")
+        fe = QueryFrontend(rt, max_batch=2)
+        operand_bytes = rt.metrics.counter(obs.PLANNER_OPERAND_BYTES)
+        log_dir = str(tmp_path_factory.mktemp("q6_trace"))
+        jax.profiler.start_trace(log_dir)
+        try:
+            for specs in Q6_SPECS:
+                fe.submit("t", *predicate_plan(table, specs, rt))
+            fe.flush()
+            counts = [rt.popcount(q.result) for q in fe.take_completed()]
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+        plans = [{k: v for k, v in ev.stats}
+                 for plane in ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for ln in plane.lines for ev in ln.events
+                 if ev.name == obs.PLAN_PREDICATE]
+        runs[rows] = dict(table=table, counts=counts, plans=plans,
+                          operand_bytes=operand_bytes.total())
+        return runs[rows]
+
+    return run
+
+
+@pytest.fixture(scope="module")
+def q6_run(q6_runs):
+    return q6_runs(Q6_ROWS)
 
 
 def test_q6_answers_are_right(q6_run):
@@ -180,7 +194,17 @@ def test_plan_span_per_query(q6_run):
     assert q6_run["plans"] == [{"terms": 3, "operands": 22}] * len(Q6_SPECS)
 
 
-def test_operand_bytes_count_the_padded_operands(q6_run):
+@pytest.mark.parametrize("rows,per_operand", [
+    # 10 words: one 1024-word tile, as many bytes as 8 rows of 128 lanes
+    (Q6_ROWS, 8 * 128 * 4),
+    # 1000 words: one 1024-word tile, where 8 rows of 1024 lanes held 8x
+    (32_000, 1024 * 4),
+], ids=["10_words", "1000_words"])
+def test_operand_bytes_count_the_padded_operands(q6_runs, rows, per_operand):
     """Each launch adds its operands as the fused program receives them:
-    one row padded to 8, 10 words padded to 128 lanes, 4 bytes a word."""
-    assert q6_run["operand_bytes"] == len(Q6_SPECS) * 22 * 8 * 128 * 4
+    one row in whole 1024-word tiles, 4 bytes a word, in the singleton
+    and the stacked epoch alike; every count stays right."""
+    run = q6_runs(rows)
+    assert run["counts"] == [int(run["table"].oracle(s).sum())
+                             for s in Q6_SPECS]
+    assert run["operand_bytes"] == len(Q6_SPECS) * 22 * per_operand

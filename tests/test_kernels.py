@@ -44,6 +44,96 @@ def test_fused_bitwise_kernel(shape, expr):
         expr, env)))
 
 
+def _chain(n_ops: int) -> tuple:
+    """A ``~``-rooted expression over ``n_ops`` variables, so that any
+    pad word the kernel computes turns to ones."""
+    names = tuple(f"v{i:02d}" for i in range(n_ops))
+    v = [E.Expr.var(nm) for nm in names]
+    e = v[0]
+    for i, x in enumerate(v[1:]):
+        e = (e & x, e | x, e ^ x)[i % 3]
+    return ~e, names
+
+
+def _masked(words: np.ndarray, n_bits: int) -> np.ndarray:
+    """``words`` with every bit past ``n_bits`` cleared."""
+    out = words.copy()
+    full, rem = divmod(n_bits, 32)
+    out[..., full + (rem > 0):] = 0
+    if rem:
+        out[..., full] &= np.uint32((1 << rem) - 1)
+    return out
+
+
+def _run_path(path, expr, names, jobs, n_bits):
+    """One result per job in ``jobs`` (lists of per-name arrays), through
+    one of the pallas backend's entry points."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.core import engine
+    from repro.kernels import bitwise
+    if path == "bitwise_eval":
+        return [ops.bitwise_eval(expr, dict(zip(names, job)))
+                for job in jobs]
+    if path == "kernel_tpu_interpret":   # past-the-end reads give garbage
+        return [bitwise.fused_bitwise(expr, names, *job,
+                                      interpret=pltpu.InterpretParams())
+                for job in jobs]
+    if path.startswith("query"):
+        donate = 0 if path == "query_donated" else None
+        fn = engine._device_compiled(expr, names, "pallas", n_bits, donate)
+        # a donated buffer is gone after the call: give it a copy
+        return [fn(*[jnp.array(a, copy=True) for a in job]) for job in jobs]
+    fn = engine._device_compiled_stacked(expr, names, "pallas", n_bits)
+    return list(fn(*[a for job in jobs for a in job]))
+
+
+# (path, words, operands): every word count with two operands, and 1 and
+# 22 operands (the TPC-H Q6 predicate's plane count) at two counts.
+LANE_WORDS = (1, 127, 128, 1000, 1024, 1025, 11_111, 2 ** 14)
+LANE_PATHS = ("bitwise_eval", "kernel_tpu_interpret", "query",
+              "query_donated", "epoch2", "epoch3")
+LANE_CASES = [(p, w, 2) for p in LANE_PATHS for w in LANE_WORDS] + \
+    [(p, w, n) for p in LANE_PATHS for n in (1, 22) for w in (1025, 11_111)]
+
+
+@pytest.mark.parametrize("path,words,n_ops", LANE_CASES)
+def test_one_row_paths_match_reference(path, words, n_ops):
+    """One-row operands through every pallas entry point (read in place
+    by one launch, or stacked lane-dense by an epoch) give the numpy
+    reference's words, tail-masked to an ``n_bits`` that is not a whole
+    number of words wherever the path masks."""
+    expr, names = _chain(n_ops)
+    n_jobs = int(path[-1]) if path.startswith("epoch") else 1
+    rng = np.random.default_rng(words * 31 + n_ops)
+    jobs = [[rng.integers(0, 2**32, words, dtype=np.uint32)
+             for _ in names] for _ in range(n_jobs)]
+    n_bits = 32 * words - 5
+    got = _run_path(path, expr, names,
+                    [[jnp.asarray(a) for a in job] for job in jobs], n_bits)
+    assert len(got) == n_jobs
+    for g, job in zip(got, jobs):
+        want = E.eval_expr(expr, dict(zip(names, job)))
+        if path not in ("bitwise_eval", "kernel_tpu_interpret"):
+            want = _masked(want, n_bits)
+        assert g.shape == (words,) and g.dtype == jnp.uint32
+        assert np.array_equal(np.asarray(g), want)
+
+
+@pytest.mark.parametrize("rows,words,nbytes", [
+    (1, 5_625_000, 5_625_856 * 4),    # TPC-H Q6 at SF30: 1.00015x
+    (1, 2 ** 19, 2 ** 21),            # a 2^24-bit bitmap: no pad
+    (1, 10, 1024 * 4),
+    (8, 2 ** 19, 2 ** 24),
+    (3, 130, 8 * 256 * 4),
+])
+def test_fused_operand_bytes_by_shape(rows, words, nbytes):
+    """An operand's bytes as the fused kernel receives it, from its shape
+    alone: one row in whole 1024-word tiles, more rows in whole (8, 128)
+    tiles."""
+    assert ops.fused_operand_bytes(rows, words) == nbytes
+
+
 @pytest.mark.parametrize("shape", [(1, 1), (4, 100), (33, 257), (257, 8)])
 def test_popcount_kernel(shape):
     a = rand_u32(shape)

@@ -92,8 +92,8 @@ def test_stacked_epoch_fits_at_2_28_bits(one_chip, monkeypatch):
     """One serving epoch of 8 ``x & y`` queries over 2^28-bit bitmaps,
     as the DevicePlanner dispatches it (16 operands in their stored
     shape, job-major), stays well inside the chip's 16 GB (the 1-D
-    operands are stacked and padded to 8 rows inside the program, so it
-    needs ~6.75 GiB where the data is 0.75 GiB)."""
+    operands are stacked lane-dense inside the program, so it needs ~2
+    GiB where the data is 0.75 GiB; padded to 8 rows it needed ~6.75)."""
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     # a fresh jit, so no trace cached with the interpreter is reused
     fn = engine._device_compiled_stacked.__wrapped__(
@@ -103,6 +103,71 @@ def test_stacked_epoch_fits_at_2_28_bits(one_chip, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < 12 * GiB
+
+
+def _q6_predicate():
+    """TPC-H Q6's predicate over its 22 resident planes (SF30's widths)."""
+    from repro.apps.bitweaving_db import conjunction_plan
+    planes = {"l_shipdate": range(12), "l_discount": range(4),
+              "l_quantity": range(6)}
+    expr, env = conjunction_plan(planes, (("l_shipdate", 366, 730),
+                                          ("l_discount", 5, 7),
+                                          ("l_quantity", 0, 23)))
+    return expr, tuple(sorted(env))
+
+
+def _and_of_weeks(weeks):
+    """Ambit's Section 8.1 query: the AND of ``weeks`` 7-day ORs."""
+    names = tuple(f"d{i}" for i in range(7 * weeks))
+    v = [Expr.var(nm) for nm in names]
+    expr = None
+    for w in range(weeks):
+        week = v[7 * w]
+        for day in v[7 * w + 1:7 * w + 7]:
+            week = week | day
+        expr = week if expr is None else expr & week
+    return expr, names
+
+
+@pytest.mark.parametrize("query,words", [
+    (_q6_predicate, 5_625_000),             # TPC-H Q6 over SF30 lineitem
+    (lambda: _and_of_weeks(4), 2 ** 19),    # wau_16m, 4 weeks
+], ids=["q6_22_operands", "wau_28_operands"])
+def test_one_row_query_reads_its_operands_in_place(one_chip, monkeypatch,
+                                                   query, words):
+    """A served query over one-row operands compiles for the chip with
+    its blocks inside the kernel's VMEM budget, and reads its operands in
+    place: the program needs no temporaries to speak of (the 8-row pad of
+    Q6's 22 operands needed 3.86 GiB)."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    expr, names = query()
+    block, = bitwise.block_shape(len(names), (words,))
+    assert 2 * (len(names) + 1) * block * 4 <= bitwise.VMEM_BUDGET
+    fn = engine._device_compiled.__wrapped__(expr, names, "pallas",
+                                             32 * words, None)
+    mem = _compiled_kernel(fn, *[one_chip(words)] * len(names)
+                           ).memory_analysis()
+    assert mem.temp_size_in_bytes < GiB
+
+
+@pytest.mark.parametrize("program", ["ambit_query", "ambit_epoch"])
+@pytest.mark.parametrize("words", [1, 127, 512, 513, 1025, 70_001])
+def test_one_row_programs_compile_at_any_word_count(one_chip, monkeypatch,
+                                                    program, words):
+    """XLA tiles a 1-D uint32 array of up to 512 words in 128 to 512
+    words, and a longer one in 1024: both sides of that line, and word
+    counts that fill no whole tile, compile for the chip."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    expr, names = ~(X & Y) ^ ~X, ("x", "y")
+    if program == "ambit_query":
+        fn = engine._device_compiled.__wrapped__(expr, names, "pallas",
+                                                 32 * words - 5, None)
+        operands = [one_chip(words)] * 2
+    else:
+        fn = engine._device_compiled_stacked.__wrapped__(
+            expr, names, "pallas", 32 * words - 5)
+        operands = [one_chip(words)] * (3 * 2)
+    _compiled_kernel(fn, *operands)
 
 
 def test_interpret_has_no_default():
