@@ -463,18 +463,4 @@ def kernels_micro() -> List[Row]:
                  f"vs_int32_scan={4*2**20/HBM_BW*1e6:.2f}us "
                  f"traffic_saving={4*2**20/pb:.1f}x"))
 
-    m = n = 256
-    k = 4096
-    from repro.core.bitvector import pack_bits
-    a = pack_bits(jnp.asarray(rng.integers(0, 2, (m, k)), jnp.uint32))
-    b = pack_bits(jnp.asarray(rng.integers(0, 2, (n, k)), jnp.uint32))
-    us_vpu = _time(lambda: ops.binary_matmul(a, b, k))
-    us_mxu = _time(lambda: ops.binary_matmul_mxu(a, b, k))
-    xnor_ops = m * n * (k // 32) * 3  # xor+popcount+add per word
-    mxu_flops = 2 * m * n * k
-    rows.append(("kern_binary_matmul_vpu", us_vpu,
-                 f"word_ops={xnor_ops:.3g} packed_bytes={(m+n)*k//8}"))
-    rows.append(("kern_binary_matmul_mxu", us_mxu,
-                 f"mxu_flops={mxu_flops:.3g} "
-                 f"tpu_mxu_time={mxu_flops/197e12*1e6:.2f}us"))
     return rows

@@ -13,7 +13,6 @@ Prints ``name,us_per_call,derived`` CSV. Sections:
   refresh_* DRAM timing-rule oracle + refresh-interference model
   serve_*   closed-loop multi-tenant serving (continuous batching)
   faults_*  reliability: TMR tax + serving under injected faults
-  roofline_* / cell_*  dry-run roofline aggregation (SSRoofline)
 
 Machine-readable output: ``--json out.json`` additionally writes every
 row as ``{"section", "name", "us", "derived"}`` records (schema 1).
@@ -35,7 +34,7 @@ import sys
 
 def sections(trace_dir=None):
     from . import (faults, kernels_micro, paper_apps, paper_tables,
-                   refresh, roofline, serve_closed_loop)
+                   refresh, serve_closed_loop)
 
     serve = serve_closed_loop.serve_closed_loop
     if trace_dir is not None:
@@ -55,7 +54,6 @@ def sections(trace_dir=None):
         refresh.refresh,
         serve,
         faults.faults,
-        roofline.roofline_rows,
     ]
 
 

@@ -3,8 +3,8 @@
 Handles padding to lane-aligned tile multiples, backend selection
 (the Pallas interpreter on the CPU backend only; every other backend
 compiles the kernels for its device), and shape normalization.
-These are the entry points the BulkBitwiseEngine's "pallas" backend and
-the model stack use.
+These are the entry points the BulkBitwiseEngine's "pallas" backend
+uses.
 """
 
 from __future__ import annotations
@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import expr as E
-from . import binary_matmul as _bmm
 from . import bitweaving as _bw
 from . import bitwise as _bitwise
 from . import popcount as _pc
@@ -146,24 +145,3 @@ def bitweaving_scan(planes: jnp.ndarray, c1: int, c2: int) -> jnp.ndarray:
     out = _bw.bitweaving_scan(padded, int(c1), int(c2),
                               interpret=_interpret())
     return out[:words]
-
-
-def binary_matmul(a_packed: jnp.ndarray, b_packed: jnp.ndarray,
-                  k_bits: int) -> jnp.ndarray:
-    """Packed XNOR-popcount matmul: (M,Kw) x (N,Kw) -> (M,N) int32."""
-    a = jnp.asarray(a_packed, jnp.uint32)
-    b = jnp.asarray(b_packed, jnp.uint32)
-    m, kw = a.shape
-    n, _ = b.shape
-    ap = _pad_to(a, (8, 128))
-    bp = _pad_to(b, (8, 128))
-    out = _bmm.binary_matmul(ap, bp, int(k_bits), interpret=_interpret())
-    return out[:m, :n]
-
-
-def binary_matmul_mxu(a_packed: jnp.ndarray, b_packed: jnp.ndarray,
-                      k_bits: int) -> jnp.ndarray:
-    """MXU alternative: unpack to +-1 and use the systolic array (see
-    binary_matmul.py codesign note). Pure-XLA; lowers on any backend."""
-    from . import ref
-    return ref.binary_matmul_mxu(a_packed, b_packed, k_bits)

@@ -2,8 +2,7 @@
 
 Each function is the semantic ground truth the kernels are validated
 against (tests sweep shapes/dtypes and assert exact equality - these are
-integer/bit ops, so no tolerance is needed; the binary matmul oracle is
-exact integer arithmetic too).
+integer/bit ops, so no tolerance is needed).
 """
 
 from __future__ import annotations
@@ -77,32 +76,3 @@ def _pack32(bits01: jnp.ndarray) -> jnp.ndarray:
     bits01 = bits01.reshape(-1, 32).astype(jnp.uint32)
     shifts = jnp.arange(32, dtype=jnp.uint32)
     return (bits01 << shifts).sum(-1, dtype=jnp.uint32)
-
-
-def binary_matmul(a_packed: jnp.ndarray, b_packed: jnp.ndarray,
-                  k_bits: int) -> jnp.ndarray:
-    """XNOR-popcount matmul over {-1,+1} vectors packed as bits (1 = +1).
-
-    a_packed: (M, K/32) uint32, b_packed: (N, K/32) uint32.
-    Returns (M, N) int32 with C[m,n] = sum_k a[m,k]*b[n,k]
-                                     = k_bits - 2*popcount(a XOR b).
-    Padding bits beyond k_bits must be zero in both operands (they cancel:
-    0 XOR 0 = 0 contributes popcount 0, and the formula subtracts the pad
-    via the k_bits constant).
-    """
-    x = a_packed[:, None, :] ^ b_packed[None, :, :]
-    pc = jax.lax.population_count(x).astype(jnp.int32).sum(-1)
-    pad = a_packed.shape[-1] * 32 - k_bits
-    # pad bits are 0^0=0 -> contribute 0 to popcount; dot over k_bits only.
-    return jnp.int32(k_bits) - 2 * pc
-
-
-def binary_matmul_mxu(a_packed: jnp.ndarray, b_packed: jnp.ndarray,
-                      k_bits: int) -> jnp.ndarray:
-    """MXU-path oracle: unpack to +-1 bf16 and use a real dot product.
-    (On TPU this trades 32x unpack bandwidth for MXU throughput; see
-    kernels/binary_matmul.py for the codesign discussion.)"""
-    from ..core.bitvector import unpack_bits
-    a = unpack_bits(a_packed)[..., :k_bits].astype(jnp.float32) * 2 - 1
-    b = unpack_bits(b_packed)[..., :k_bits].astype(jnp.float32) * 2 - 1
-    return jnp.dot(a, b.T).astype(jnp.int32)

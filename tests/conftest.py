@@ -6,8 +6,7 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 # NOTE: do NOT set XLA_FLAGS / host device count here - smoke tests and
-# benchmarks must see the single real CPU device. Multi-device tests spawn
-# subprocesses that set the flag themselves (see test_distributed.py).
+# benchmarks must see the single real CPU device.
 
 # -- ledger determinism hook --------------------------------------------------
 # pim tests record the ledgers their canonical workloads produce; when

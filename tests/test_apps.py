@@ -115,20 +115,9 @@ def test_masked_init(engine):
 
 
 def test_data_pipeline_bitweaving_filter():
-    from repro.data.pipeline import filter_documents, synth_corpus_meta
+    from repro.apps.doc_filter import filter_documents, synth_corpus_meta
     meta = synth_corpus_meta(2048, seed=1)
     mask = filter_documents(meta, 64, 200, 1000)
     expect = ((meta.quality >= 64) & (meta.quality <= 200) &
               (meta.length >= 1000))
     assert np.array_equal(mask, expect)
-
-
-def test_data_pipeline_resume_determinism():
-    from repro.data.pipeline import DataConfig, SyntheticLM
-    data = SyntheticLM(DataConfig(vocab=100, seq_len=16, global_batch=4))
-    a = data.batch_at(7)
-    b = data.batch_at(7)
-    assert np.array_equal(a["tokens"], b["tokens"])
-    # shards partition the batch deterministically
-    s0 = data.batch_at(7, shard=0, n_shards=2)
-    assert s0["tokens"].shape[0] == 2

@@ -171,21 +171,6 @@ def test_bitweaving_kernel(b, n):
     assert np.array_equal(mask, (vals >= lo) & (vals <= hi))
 
 
-@pytest.mark.parametrize("m,n,k", [(1, 1, 32), (5, 9, 64), (16, 16, 128),
-                                   (40, 70, 1000), (8, 128, 4096)])
-def test_binary_matmul_kernel(m, n, k):
-    kw = (k + 31) // 32
-    abits = RNG.integers(0, 2, (m, k)).astype(np.uint32)
-    bbits = RNG.integers(0, 2, (n, k)).astype(np.uint32)
-    ap = pack_bits(jnp.asarray(abits))[:, :kw]
-    bp = pack_bits(jnp.asarray(bbits))[:, :kw]
-    expect = (2 * abits.astype(np.int32) - 1) @ \
-        (2 * bbits.astype(np.int32) - 1).T
-    assert np.array_equal(np.asarray(ops.binary_matmul(ap, bp, k)), expect)
-    assert np.array_equal(np.asarray(ops.binary_matmul_mxu(ap, bp, k)),
-                          expect)
-
-
 # -- engine invariants (randomized) -------------------------------------------
 # Shared check bodies; hypothesis drives them when installed, deterministic
 # seeded sweeps otherwise.

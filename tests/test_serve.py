@@ -1,5 +1,4 @@
-"""Serving layer: ServeEngine termination regressions + QueryFrontend
-differential and property suite.
+"""Serving layer: QueryFrontend differential and property suite.
 
 The frontend reorders WHEN queries run (admission quotas, batching
 windows, epoch packing) but must never change WHAT they compute or what
@@ -20,10 +19,6 @@ the ledgers record:
     the count matches the host computation bit-for-bit while only the
     int32 scalar (4 bytes) crosses the channel.
 
-ServeEngine regressions pin the termination contract: the
-prefill-sampled token is EOS-checked like every other token, and padded
-slots of a partial batch never keep the decode loop alive.
-
 Property tests run under hypothesis when installed; without it they fall
 back to deterministic seeded sweeps over the same generators.
 """
@@ -42,8 +37,7 @@ import jax
 from repro.core import AmbitError, BitVector, DRAMGeometry, Expr
 from repro.core.engine import OpStats
 from repro.pim import AmbitRuntime
-from repro.serve import (QueryFrontend, Request, ServeEngine, TenantQuota,
-                         run_closed_loop)
+from repro.serve import QueryFrontend, TenantQuota, run_closed_loop
 
 GEOM = DRAMGeometry(rows_per_subarray=32)  # compact devices
 BACKENDS = ("ambit_sim", "jnp", "pallas")
@@ -65,105 +59,6 @@ def _rt(backend="ambit_sim", **kw):
 def _operands(rt, rng, n=4, n_bits=120):
     bits = rng.integers(0, 2, (n, n_bits)).astype(bool)
     return bits, [rt.put(BitVector.from_bits(b)) for b in bits]
-
-
-# -- ServeEngine termination regressions --------------------------------------
-
-
-class _StubModel:
-    """Deterministic LM: next token = (last token + 1) mod V under
-    argmax, so generations are predictable without real weights."""
-
-    V = 16
-
-    def prefill(self, params, batch, skv=None):
-        last = batch["tokens"][:, -1]
-        return jax.nn.one_hot((last + 1) % self.V, self.V), {"t": last}
-
-    def decode_step(self, params, caches, batch):
-        last = batch["tokens"][:, 0]
-        return jax.nn.one_hot((last + 1) % self.V, self.V), caches
-
-
-def _engine(batch_slots=2, max_seq=32):
-    return ServeEngine(_StubModel(), {}, max_seq=max_seq,
-                       batch_slots=batch_slots)
-
-
-def test_eos_on_prefill_token_regression():
-    """The token sampled from the PREFILL logits is EOS-checked too: a
-    request whose first generated token is EOS produces no output and
-    costs zero decode steps (it used to be appended unconditionally)."""
-    eng = _engine()
-    reqs = [Request(prompt=np.array([5], np.int32), max_new_tokens=8,
-                    eos_id=6)]
-    eng.generate(reqs)
-    assert reqs[0].out == []
-    assert reqs[0].done
-    assert eng.decode_steps == 0
-
-
-def test_eos_mid_stream_stops_decoding():
-    eng = _engine()
-    reqs = [Request(prompt=np.array([3], np.int32), max_new_tokens=10,
-                    eos_id=7)]
-    eng.generate(reqs)
-    assert reqs[0].out == [4, 5, 6]     # 7 is EOS: checked, not emitted
-    assert reqs[0].done
-    assert eng.decode_steps == 3
-
-
-def test_partial_batch_padded_slots_do_not_prolong_decode():
-    """One real request in a 4-slot batch: the loop runs exactly the
-    decode steps the real request needs - padded slots are born done."""
-    eng = _engine(batch_slots=4)
-    reqs = [Request(prompt=np.array([1], np.int32), max_new_tokens=3)]
-    eng.generate(reqs)
-    assert reqs[0].out == [2, 3, 4]
-    assert eng.decode_steps == 2        # prefill token + 2 decode tokens
-
-
-def test_mixed_eos_batch_counts_exact_decode_steps():
-    """Batchmates finish at different times; the loop runs only until
-    the LAST real request is done."""
-    eng = _engine(batch_slots=2)
-    reqs = [Request(prompt=np.array([5], np.int32), max_new_tokens=8,
-                    eos_id=7),          # 6 then EOS: done after 1 decode
-            Request(prompt=np.array([1], np.int32), max_new_tokens=4)]
-    eng.generate(reqs)
-    assert reqs[0].out == [6]
-    assert reqs[1].out == [2, 3, 4, 5]
-    assert eng.decode_steps == 3
-
-
-def test_generate_empty_and_single_token():
-    eng = _engine()
-    assert eng.generate([]) == []
-    reqs = [Request(prompt=np.array([1, 2], np.int32), max_new_tokens=1)]
-    eng.generate(reqs)
-    assert reqs[0].out == [3] and reqs[0].done
-    assert eng.decode_steps == 0
-
-
-def test_generate_validates_before_running():
-    eng = _engine(max_seq=8)
-    with pytest.raises(ValueError, match="exceeds max_seq"):
-        eng.generate([Request(prompt=np.arange(9, dtype=np.int32))])
-    with pytest.raises(ValueError, match="empty prompt"):
-        eng.generate([Request(prompt=np.array([], np.int32))])
-    with pytest.raises(ValueError, match="max_new_tokens"):
-        eng.generate([Request(prompt=np.array([1], np.int32),
-                              max_new_tokens=0)])
-    assert eng.decode_steps == 0        # no partial generation on bad input
-
-
-def test_max_seq_bounds_generation():
-    eng = _engine(max_seq=4)
-    reqs = [Request(prompt=np.array([1, 2, 3], np.int32),
-                    max_new_tokens=10)]
-    eng.generate(reqs)
-    # pos would step past the KV cache: only the prefill token fits
-    assert reqs[0].out == [4] and reqs[0].done
 
 
 # -- frontend differential: served == serial, ledgers conserved ---------------
@@ -503,22 +398,6 @@ def test_frontend_metrics_reconcile_with_report():
     assert m.counter("serve_admitted").total() == rep.completed
     assert lat.percentile(0.50) == rep.p50_ns
     assert lat.percentile(0.99) == rep.p99_ns
-
-
-def test_serve_engine_metrics_counters():
-    eng = _engine(batch_slots=2)
-    reqs = [Request(prompt=np.array([5], np.int32), max_new_tokens=8,
-                    eos_id=7),
-            Request(prompt=np.array([1], np.int32), max_new_tokens=4)]
-    eng.generate(reqs)
-    m = eng.metrics
-    assert m.counter("serve_prefill_batches").total() == 1
-    assert m.counter("serve_decode_steps").total() == eng.decode_steps
-    assert m.counter("serve_tokens_sampled").total() == \
-        sum(len(r.out) for r in reqs)
-    assert m.counter("serve_requests_completed").value(reason="eos") == 1
-    assert m.counter("serve_requests_completed").value(
-        reason="max_new_tokens") == 1
 
 
 def test_device_popcount_refuses_int32_overflow():

@@ -19,8 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import Expr, engine
-from repro.kernels import (binary_matmul, bitweaving, bitwise, ops,
-                           popcount)
+from repro.kernels import bitweaving, bitwise, ops, popcount
 
 GiB = 2 ** 30
 X, Y = Expr.var("x"), Expr.var("y")
@@ -174,8 +173,7 @@ def test_interpret_has_no_default():
     """Every kernel takes ``interpret`` explicitly: none falls back to
     the interpreter because a caller left the flag out."""
     for fn in (bitwise.fused_bitwise, bitwise.fused_bitwise_stacked,
-               popcount.popcount_rows, bitweaving.bitweaving_scan,
-               binary_matmul.binary_matmul):
+               popcount.popcount_rows, bitweaving.bitweaving_scan):
         param = inspect.signature(fn).parameters["interpret"]
         assert param.default is inspect.Parameter.empty, fn.__name__
 
@@ -194,7 +192,6 @@ def _program_names(fn, *args, **static):
     ("fused_bitwise_stacked", "fused_bitwise_stacked"),
     ("popcount_rows", "popcount_rows"),
     ("bitweaving_scan", "bitweaving_scan"),
-    ("binary_matmul", "binary_matmul"),
     ("ambit_query", "fused_bitwise"),
     ("ambit_epoch", "fused_bitwise_stacked"),
 ])
@@ -214,9 +211,6 @@ def test_trace_names_are_stable(one_chip, monkeypatch, program, kernel):
                                   one_chip(8, 2 ** 14)),
         "bitweaving_scan": lambda: (bitweaving.bitweaving_scan,
                                     one_chip(12, 2 ** 14), 100, 3000),
-        "binary_matmul": lambda: (binary_matmul.binary_matmul,
-                                  one_chip(64, 128), one_chip(64, 128),
-                                  4096),
         "ambit_query": lambda: (engine._device_compiled.__wrapped__(
             *xy, "pallas", 2 ** 19, None), one_chip(2 ** 14),
             one_chip(2 ** 14)),
