@@ -23,6 +23,7 @@ i.e. firmly HBM-bound, which is precisely the regime Ambit targets
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -38,6 +39,14 @@ BLOCK_WORDS = 512
 # that a v5e kernel may use by default, with room for the strip's
 # intermediates.
 VMEM_BUDGET = 12 * 2 ** 20
+
+
+def reads_flat(shape: Tuple[int, ...]) -> bool:
+    """Whether a (..., words) operand of ``shape`` goes to a kernel as its
+    flat (words,) array, read in place: one row of more than half a tile
+    of SUBLANES x LANES words, as HBM stores such an array in tiles that
+    hold the bytes of one (8, 128) tile."""
+    return math.prod(shape[:-1]) == 1 and shape[-1] > SUBLANES * LANES // 2
 
 
 def block_shape(n_operands: int, shape: Tuple[int, ...]) -> Tuple[int, ...]:

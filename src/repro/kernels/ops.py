@@ -67,7 +67,7 @@ def _to_kernel(a: jnp.ndarray, stacked: bool = False) -> jnp.ndarray:
     rows, words = _rows_words(a.shape)
     if rows > 1:
         return _pad_to(a.reshape(rows, words), FUSED_TILE)
-    if not stacked and words > LANE_TILE // 2:
+    if not stacked and _bitwise.reads_flat(a.shape):
         return a.reshape(words)
     return _pad_to(a.reshape(words), (LANE_TILE,)).reshape(
         -1, _bitwise.LANES)
@@ -127,14 +127,11 @@ def bitwise_eval_stacked(expression: E.Expr, names,
 
 
 def popcount(x: jnp.ndarray) -> jnp.ndarray:
-    """Per-row popcount: (..., words) uint32 -> (...,) int32."""
-    x = jnp.asarray(x, jnp.uint32)
-    lead = x.shape[:-1]
-    words = x.shape[-1]
-    rows = int(np.prod(lead)) if lead else 1
-    x2 = _pad_to(x.reshape(rows, words), (8, 128))
-    out = _pc.popcount_rows(x2, interpret=_interpret())[:rows]
-    return out.reshape(lead) if lead else out[0]
+    """Per-row popcount: (..., words) uint32 -> (...,) int32, as one
+    dispatch of the jitted ``popcount.popcount_rows``, which reads a
+    one-row operand flat where it lies and pads any other inside."""
+    return _pc.popcount_rows(jnp.asarray(x, jnp.uint32),
+                             interpret=_interpret())
 
 
 def bitweaving_scan(planes: jnp.ndarray, c1: int, c2: int) -> jnp.ndarray:

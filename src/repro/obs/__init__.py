@@ -18,7 +18,8 @@ from .export import chrome_trace, utilization_report, write_chrome_trace
 from .host import (FRONTEND_DRAIN, FRONTEND_SUBMIT, PLAN_PREDICATE,
                    PLANNER_EPOCH, PLANNER_LAUNCH, PLANNER_OPERAND_BYTES,
                    PLANNER_STACK, PLANNER_STACK_BYTES, SCHEDULER_DRAIN,
-                   STORE_POPCOUNT, STORE_POPCOUNT_WAIT, host_span)
+                   STORE_POPCOUNT, STORE_POPCOUNT_FLAT, STORE_POPCOUNT_WAIT,
+                   host_span)
 
 __all__ = [
     "NULL_TRACER",
@@ -43,4 +44,5 @@ __all__ = [
     "PLAN_PREDICATE",
     "PLANNER_STACK_BYTES",
     "PLANNER_OPERAND_BYTES",
+    "STORE_POPCOUNT_FLAT",
 ]

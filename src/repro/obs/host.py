@@ -31,7 +31,9 @@ the epoch program writes into operand stacks on the device (queries x
 operands x bytes per operand). ``PLANNER_OPERAND_BYTES`` counts the
 bytes of operands as the fused program receives them, after its row and
 lane padding (queries x operands x padded bytes per operand), at every
-launch, singleton or stacked.
+launch, singleton or stacked. ``STORE_POPCOUNT_FLAT`` counts the
+``DeviceStore.popcount`` calls whose kernel reads the bitvector flat,
+where it lies (``kernels.bitwise.reads_flat``).
 """
 
 from __future__ import annotations
@@ -50,6 +52,7 @@ PLAN_PREDICATE = "repro.plan.predicate"
 
 PLANNER_STACK_BYTES = "planner_stack_bytes"
 PLANNER_OPERAND_BYTES = "planner_operand_bytes"
+STORE_POPCOUNT_FLAT = "store_popcount_flat"
 
 
 def host_span(name: str, **stats) -> TraceAnnotation:

@@ -49,8 +49,8 @@ from ..core.engine import (OpStats, _device_compiled,
                            _device_compiled_stacked)
 from ..core.simulator import AmbitError
 from ..obs import (PLANNER_LAUNCH, PLANNER_OPERAND_BYTES, PLANNER_STACK,
-                   PLANNER_STACK_BYTES, STORE_POPCOUNT, STORE_POPCOUNT_WAIT,
-                   host_span)
+                   PLANNER_STACK_BYTES, STORE_POPCOUNT, STORE_POPCOUNT_FLAT,
+                   STORE_POPCOUNT_WAIT, host_span)
 from .store import LruSpillBase
 
 
@@ -242,16 +242,19 @@ class DeviceStore(LruSpillBase):
 
     def popcount(self, rbv: DeviceBitVector) -> int:
         """Count set bits WITHOUT reading the bitvector back: the
-        reduction runs on the accelerator (pallas popcount kernel on the
-        pallas backend, ``lax.population_count`` on jnp) and only one
-        int32 count per row crosses to the host - 4 ledger bytes per row
-        instead of the whole array. Rows sum on the host in int64, and a
-        handle with 2^31 or more bits per row is refused rather than
-        wrapping its int32 count. Device arrays are tail-masked by
-        construction (put data comes from packed BitVectors; planner
-        results are masked in ``_device_compiled``), so the full-array
-        count is exact. Spilled handles count their current host copy
-        for free."""
+        reduction runs on the accelerator and only one int32 count per
+        row crosses to the host - 4 ledger bytes per row instead of the
+        whole array. On the pallas backend a live handle costs one
+        dispatch of the jitted ``popcount_rows`` on its buffer as it
+        lies (a one-row handle's kernel reads it flat and counts in
+        ``store_popcount_flat``), then one blocking read; the jnp
+        backend reduces with ``lax.population_count``. Rows sum on the
+        host in int64, and a handle with 2^31 or more bits per row is
+        refused rather than wrapping its int32 count. Device arrays are
+        tail-masked by construction (put data comes from packed
+        BitVectors; planner results are masked in
+        ``_device_compiled``), so the full-array count is exact.
+        Spilled handles count their current host copy for free."""
         with host_span(STORE_POPCOUNT):
             self._check_handle(rbv)
             if rbv.words32 * 32 >= 2 ** 31:
@@ -261,17 +264,19 @@ class DeviceStore(LruSpillBase):
             if rbv.spilled:
                 return int(np.asarray(rbv._host.popcount(), np.int64).sum())
             self._touch(rbv)
-            dev = rbv._dev.reshape(-1, rbv.words32)
             if self.backend == "pallas":
-                from ..kernels import ops as kops
-                per_row = kops.popcount(dev)
+                from ..kernels import bitwise, ops as kops
+                if bitwise.reads_flat(rbv._dev.shape):
+                    self.metrics.counter(STORE_POPCOUNT_FLAT).inc(1)
+                counts = kops.popcount(rbv._dev)
             else:
-                per_row = jax.lax.population_count(dev).astype(
+                counts = jax.lax.population_count(
+                    rbv._dev.reshape(-1, rbv.words32)).astype(
                     jnp.int32).sum(-1)
             with host_span(STORE_POPCOUNT_WAIT):
-                per_row = np.asarray(per_row, np.int64)
-            self._charge_io("from_device", "popcount", 4 * dev.shape[0])
-            return int(per_row.sum())
+                counts = np.asarray(counts, np.int64)
+            self._charge_io("from_device", "popcount", 4 * counts.size)
+            return int(counts.sum())
 
 
 @dataclasses.dataclass

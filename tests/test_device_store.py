@@ -278,6 +278,78 @@ def test_stacked_epoch_stacks_and_slices_inside_its_program(
         assert np.array_equal(np.asarray(res._dev), np.asarray(w._dev))
 
 
+def _refuse_concrete(real):
+    """``real``, raising where it is called on concrete arrays alone,
+    i.e. eagerly, outside a jitted program's trace."""
+    def guarded(*args, **kwargs):
+        leaves = jax.tree_util.tree_leaves((args, kwargs))
+        if not any(isinstance(x, jax.core.Tracer) for x in leaves):
+            raise AssertionError(f"eager {real.__name__} in a popcount")
+        return real(*args, **kwargs)
+    return guarded
+
+
+def test_store_popcount_is_one_dispatch_that_reads_flat(monkeypatch):
+    """On the pallas backend a count is one call of the jitted
+    ``popcount_rows`` on the handle's buffer as it lies, with no eager
+    reshape, pad or slice around it. A one-row handle of a length that
+    is no multiple of a block or a strip counts exactly and bumps
+    ``store_popcount_flat``; a multi-row handle does not."""
+    from repro.kernels import popcount as kpc
+    from repro.obs import STORE_POPCOUNT_FLAT
+    rng = np.random.default_rng(18)
+    store = DeviceStore("pallas")
+    one = rng.integers(0, 2, 32 * 5001 + 13).astype(bool)
+    two = rng.integers(0, 2, (2, 32 * 700 + 5)).astype(bool)
+    h1, h2 = (store.put(BitVector.from_bits(b)) for b in (one, two))
+    flat = store.metrics.counter(STORE_POPCOUNT_FLAT)
+    calls = []
+    real = kpc.popcount_rows
+
+    def counted(x, **static):
+        calls.append(x)
+        return real(x, **static)
+
+    array_type = type(h1._dev)
+    monkeypatch.setattr(kpc, "popcount_rows", counted)
+    monkeypatch.setattr(jax.numpy, "pad", _refuse_concrete(jax.numpy.pad))
+    for method in ("__getitem__", "reshape"):
+        monkeypatch.setattr(array_type, method,
+                            _refuse_concrete(getattr(array_type, method)))
+    got1 = store.popcount(h1)
+    calls1, flat1 = list(calls), flat.value()
+    got2 = store.popcount(h2)
+    monkeypatch.undo()
+    assert got1 == int(one.sum()) and got2 == int(two.sum())
+    assert len(calls1) == 1 and calls1[0] is h1._dev and flat1 == 1
+    assert len(calls) == 2 and calls[1] is h2._dev and flat.value() == 1
+
+
+def _primitives(jaxpr) -> list:
+    """Every primitive of ``jaxpr`` and of the jaxprs it holds, a
+    pallas_call's kernel included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                names += _primitives(inner)
+    return names
+
+
+def test_store_popcount_program_has_one_kernel_and_no_pad():
+    """The program a one-row count dispatches, for a (words,) buffer:
+    the jitted ``popcount_rows`` alone at the top, and within it one
+    pallas_call and no pad."""
+    jaxpr = jax.make_jaxpr(kops.popcount)(
+        jax.numpy.zeros(5001, jax.numpy.uint32)).jaxpr
+    assert [(e.primitive.name, e.params.get("name")) for e in jaxpr.eqns] \
+        == [("jit", "popcount_rows")]
+    prims = _primitives(jaxpr)
+    assert prims.count("pallas_call") == 1 and "pad" not in prims
+
+
 def test_drain_dependency_and_out_rebind():
     """Ticket deps execute in earlier epochs; out= rebinds preserve the
     destination handle's identity (device-buffer move, no copy)."""

@@ -20,7 +20,7 @@ except ImportError:  # deterministic fallbacks below keep coverage
 from repro.core import BitVector, BulkBitwiseEngine
 from repro.core import expr as E
 from repro.core.bitvector import pack_bits, unpack_bits
-from repro.kernels import ops, ref
+from repro.kernels import bitwise, ops, ref
 
 RNG = np.random.default_rng(3)
 
@@ -156,6 +156,29 @@ def test_popcount_rows_counts_no_word_past_the_end(words, fill):
     got = popcount.popcount_rows(x, interpret=pltpu.InterpretParams())
     want = jax.lax.population_count(x).astype(jnp.int32).sum(1)
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+# the flat popcount's block: 786,432 words, for any array of more
+BLOCK = bitwise.block_shape(1, (2 ** 24,))[0]
+
+
+@pytest.mark.parametrize("words", [513, 1024, 1025, BLOCK - 1, BLOCK + 1,
+                                   3 * BLOCK + 7])
+@pytest.mark.parametrize("fill", ["ones", "random"])
+def test_popcount_flat_counts_no_word_past_the_end(words, fill):
+    """A one-row operand is read flat, in place: a block, and its last
+    strip of (8, 128) words, may reach past the array, where TPU
+    interpret mode reads uninitialised words (all ones) as the chip reads
+    whatever lies there. Only the array's words may count, in one block
+    or several, whole or not."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels import popcount
+    x = jnp.full((words,), 0xFFFFFFFF, jnp.uint32) if fill == "ones" \
+        else rand_u32((words,))
+    got = popcount.popcount_rows(x, interpret=pltpu.InterpretParams())
+    assert got.shape == ()
+    assert int(got) == int(ref.popcount(x))
 
 
 @pytest.mark.parametrize("b,n", [(1, 32), (4, 64), (8, 320), (12, 1024),

@@ -74,12 +74,14 @@ def test_popcount_rows_compiles(one_chip):
                      interpret=False)
 
 
-def test_popcount_compiles_past_whole_blocks(one_chip, monkeypatch):
-    """The runtime popcount of one TPC-H Q6 selection at SF30: 5,625,000
-    words, which the wrapper pads to 5,625,088, half a 512-word block
-    past the last whole one, so the kernel masks its last block."""
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
-    _compiled_kernel(jax.jit(ops.popcount), one_chip(5_625_000))
+@pytest.mark.parametrize("words", [5_625_000, 2 ** 19])
+def test_popcount_compiles_past_whole_blocks(one_chip, words):
+    """The runtime popcount's one program, ``popcount_rows``, as it
+    counts one selection: TPC-H Q6's at SF30, 5,625,000 words, read flat
+    in 786,432-word blocks, the last of them partial and masked past the
+    array's end; and one 2^24-bit bitmap, 2^19 words, one whole block."""
+    _compiled_kernel(popcount.popcount_rows, one_chip(words),
+                     interpret=False)
 
 
 def test_bitweaving_scan_compiles(one_chip):
